@@ -9,8 +9,8 @@ reference oracle, and report model GF/s plus measured wall time.
 import argparse
 import time
 
-from repro.codegen import (allclose, plan_executor, random_inputs,
-                           reference_executor)
+from repro.codegen import (allclose, enable_compile_cache, plan_executor,
+                           random_inputs, reference_executor)
 from repro.core import THREE_SLICE, SolverOptions, polybench, solve
 
 EXECUTABLE = ["3mm", "2mm", "gemm", "atax", "bicg", "mvt", "gesummv",
@@ -26,6 +26,7 @@ def main() -> None:
                     choices=("xla", "pallas_interpret", "pallas"),
                     help="kernel implementation (default: auto)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"{'kernel':10s} {'GF/s(model)':>12s} {'solver_s':>9s} "
           f"{'exec_ms':>8s} {'lowered':>12s} {'validated':>9s}")

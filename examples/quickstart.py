@@ -16,14 +16,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import frontend
-from repro.codegen import (allclose, plan_executor, random_inputs,
-                           reference_executor)
+from repro.codegen import (allclose, enable_compile_cache, plan_executor,
+                           random_inputs, reference_executor)
 from repro.core import (ONE_SLICE, THREE_SLICE, SolverOptions, polybench,
                         solve)
 from repro.core.fusion import fuse
 
 
 def main() -> None:
+    enable_compile_cache()
     g = polybench.build("3mm")
     print(f"== task graph: {g.name} ==")
     print(f"statements: {[s.name for s in g.statements]}")

@@ -10,6 +10,7 @@ import time
 import jax
 import numpy as np
 
+from repro.codegen import enable_compile_cache
 from repro.configs import get_config, list_archs
 from repro.configs.base import smoke
 from repro.models import model as M
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--kv-dtype", default="bfloat16",
                     choices=["bfloat16", "int8", "float32"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     import dataclasses
     cfg = dataclasses.replace(smoke(get_config(args.arch)),
@@ -47,8 +49,10 @@ def main() -> None:
     print(f"arch={args.arch} kv={args.kv_dtype} "
           f"batch={args.batch} prompt={args.prompt_len} "
           f"new={args.new_tokens}")
+    dev = jax.devices()[0]
     print(f"generated {out.shape} in {dt:.2f}s "
-          f"-> {stats['tokens_per_s']:.1f} tok/s (CPU interpret)")
+          f"-> {stats['tokens_per_s']:.1f} tok/s on {dev.platform} "
+          f"({dev.device_kind})")
     print("sample:", out[0, :16].tolist())
     print("serve_lm OK")
 

@@ -16,6 +16,7 @@ lowers — here jitted on the local device mesh.
 import argparse
 import dataclasses
 
+from repro.codegen import enable_compile_cache
 from repro.configs import get_config, list_archs
 from repro.configs.base import smoke
 from repro.ft import FailurePlan
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--inject-failure-at", type=int, default=None,
                     help="test checkpoint-restart by failing at this step")
     args = ap.parse_args()
+    enable_compile_cache()
 
     L, d, h, kv, ff, v = PRESETS[args.preset]
     base = smoke(get_config(args.arch))

@@ -25,7 +25,8 @@ the same lowering targets JAX/Pallas:
 from .executor import PlanExecutable, plan_executor
 from .lower import LoweredUnit, TaskLowering, lower_task
 from .program import (PlanProgram, ProgramCache, cache_stats,
-                      clear_program_cache, compiled_program,
+                      clear_program_cache, compile_cache_dir,
+                      compiled_program, enable_compile_cache,
                       enable_persistent_cache, graph_fingerprint,
                       persistent_cache_dir, plan_fingerprint, program_cache,
                       program_key, set_program_cache_size)
@@ -41,7 +42,8 @@ __all__ = [
     "PlanProgram", "ProgramCache", "compiled_program", "cache_stats",
     "clear_program_cache", "graph_fingerprint", "plan_fingerprint",
     "program_cache", "program_key", "set_program_cache_size",
-    "enable_persistent_cache", "persistent_cache_dir",
+    "compile_cache_dir", "enable_compile_cache", "enable_persistent_cache",
+    "persistent_cache_dir",
     "Transfer", "WaveSchedule", "wave_schedule",
     "allclose", "assert_close", "eval_statement",
     "random_inputs", "reference_executor",

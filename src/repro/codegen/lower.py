@@ -127,6 +127,16 @@ def _affine(stmt: Statement) -> bool:
     return True
 
 
+def _plan_tiled(task: FusedTask, stmt: Statement) -> bool:
+    """Some access of ``stmt`` runs over a loop the plan tiles (the task's
+    main loops).  A pointwise statement whose every iterator is private
+    has no tiling in the plan: a kernel for it would hold whole arrays in
+    VMEM, so it runs as a plain XLA op (or rides a producer's epilogue)."""
+    main = set(task.main.loops)
+    return any(it in main for acc in tuple(stmt.reads) + tuple(stmt.writes)
+               for it in acc.iters)
+
+
 def _acc_reads(stmt: Statement):
     out = stmt.writes[0]
     return [a for a in stmt.reads if a.array == out.array]
@@ -228,10 +238,10 @@ def _build_units(fg: FusedGraph, task: FusedTask,
                 f"{stmt.name}: triangular-density statements are "
                 "cost-modeled only (rectangular execution would compute a "
                 "different function)")
-        if not _affine(stmt):
+        if not _affine(stmt) or not _plan_tiled(task, stmt):
             # outside the kernel subset: eval fallback, one statement —
             # "opaque" marks frontend passthrough segments (registered
-            # residual callables), "einsum" the affine-but-untileable rest
+            # residual callables), "einsum" the affine-but-untiled rest
             flush_init()
             srcs = tuple(dict.fromkeys(a.array for a in stmt.reads))
             kind = "opaque" if stmt.op.startswith(OPAQUE_PREFIX) \

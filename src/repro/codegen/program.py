@@ -26,10 +26,10 @@ generation of that engine, with three production mechanisms on top:
   pool are thread-safe: concurrent servers hit under the cache lock,
   misses for the same key compile once behind a per-key build lock, and
   the round-robin cursor never hands two callers the same clone index.
-  A persistent AOT cache (``jax_compilation_cache_dir``, exposed as
-  :func:`enable_persistent_cache` / ``REPRO_COMPILATION_CACHE_DIR``) lets
-  replicas share lowered XLA artifacts across processes: a warm replica's
-  first compile of a known program deserializes instead of re-lowering.
+  JAX's persistent compilation cache (:func:`enable_compile_cache`, at
+  the directory :func:`compile_cache_dir` resolves) lets processes share
+  compiled artifacts: a warm process's first compile of a known program
+  deserializes instead of re-lowering.
 
 The input shapes/dtypes dimension of the cache key is carried by
 ``jax.jit``'s own aval cache underneath, so a repeated call with identical
@@ -94,21 +94,45 @@ def program_key(graph: TaskGraph, plan: ExecutionPlan,
 
 
 # ---------------------------------------------------------------------------
-# Persistent AOT compilation cache (cross-process artifact sharing)
+# Persistent compilation cache (cross-process artifact sharing)
 # ---------------------------------------------------------------------------
+#: The cache directory when ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed
+#: path inside the checkout (listed in ``.gitignore``).  The path is part of
+#: what a cache entry is found under, so it must not move between runs.
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
 _persistent_dir: str | None = None
+
+
+def compile_cache_dir() -> str:
+    """The one place the compile-cache directory is resolved:
+    ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it itself), else
+    :data:`DEFAULT_COMPILE_CACHE`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or DEFAULT_COMPILE_CACHE
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache at :func:`compile_cache_dir`
+    — what entry points (``chip_smoke.py``, the examples) call at start."""
+    return enable_persistent_cache(compile_cache_dir())
 
 
 def enable_persistent_cache(path: str) -> str:
     """Point JAX's persistent compilation cache at ``path`` and open it up
     to every program this engine compiles (no min-size / min-compile-time
-    cutoffs — plan programs are small but re-lowered by every replica).
+    cutoffs — plan programs are small but re-lowered by every process).
+    When ``JAX_COMPILATION_CACHE_DIR`` names ``path`` already, JAX holds
+    it and no directory is set here.
 
     Returns the directory so callers can log/inspect it.  Safe to call more
     than once; the last directory wins process-wide.
     """
     global _persistent_dir
-    # Crash hygiene before trusting the directory: a replica killed
+    os.makedirs(path, exist_ok=True)
+    # Crash hygiene before trusting the directory: a process killed
     # mid-write leaves zero-byte entries / orphaned temp files that would
     # otherwise surface as deserialization errors on the next warm start.
     # Scrubbed entries are simply recompiled (logged by the scrubber).
@@ -131,17 +155,15 @@ def enable_persistent_cache(path: str) -> str:
     except (ArtifactError, OSError) as exc:
         quarantine_file(meta_path, reason=repr(exc))
         atomic_write_json(meta_path, meta)
-    jax.config.update("jax_compilation_cache_dir", path)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR") != path:
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     if _persistent_dir != path:
         # jax latches the cache backend on first compile; a process that
         # already compiled anything would otherwise silently never persist
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except (ImportError, AttributeError):
-            pass
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
     _persistent_dir = path
     return path
 
@@ -149,13 +171,6 @@ def enable_persistent_cache(path: str) -> str:
 def persistent_cache_dir() -> str | None:
     """The active persistent-cache directory, if any."""
     return _persistent_dir
-
-
-def _auto_enable_persistent_cache() -> None:
-    if _persistent_dir is None:
-        path = os.environ.get("REPRO_COMPILATION_CACHE_DIR")
-        if path:
-            enable_persistent_cache(path)
 
 
 # ---------------------------------------------------------------------------
@@ -332,6 +347,23 @@ class PlanProgram:
         for lw in self.lowered.values():
             for u in lw.units:
                 out[u.kind] = out.get(u.kind, 0) + 1
+        return out
+
+    def lower(self, inputs: dict, sharding=None) -> list:
+        """Each segment lowered (``jax.stages.Lowered``) for inputs of these
+        shapes and dtypes (arrays or ``ShapeDtypeStruct``), every argument
+        placed by ``sharding`` (default: the default device).  Compiled
+        for a TPU, a Mosaic kernel shows as ``tpu_custom_call``."""
+        def shape(v):
+            return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sharding)
+
+        env = {k: shape(v) for k, v in inputs.items()}
+        out = []
+        for seg, fn in zip(self.segments, self._pool[0]):
+            args = [env[a] for a in seg.in_arrays]
+            out.append(fn.lower(*args))
+            env.update(zip(seg.out_arrays,
+                           map(shape, jax.eval_shape(fn, *args))))
         return out
 
     def est_bytes(self) -> int:
@@ -680,7 +712,6 @@ def compiled_program(graph: TaskGraph, plan: ExecutionPlan, impl: str,
     under a per-key lock (N threads missing the same cold program compile
     it once; distinct programs still compile concurrently).
     """
-    _auto_enable_persistent_cache()
     key = program_key(graph, plan, impl)
     prog = _CACHE.get_if(key, pool_size)
     if prog is not None:

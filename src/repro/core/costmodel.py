@@ -30,9 +30,88 @@ from typing import Mapping
 
 from .fusion import FusedGraph, FusedTask
 from .plan import ArrayPlacement, TaskConfig, TaskReport
-from .resources import (Hardware, STEP_OVERHEAD_S, RED_LATENCY_S, VMEM_BW,
-                        alignment_efficiency, packing_efficiency)
+from .resources import (LANE, SUBLANE, Hardware, STEP_OVERHEAD_S,
+                        RED_LATENCY_S, VMEM_BW, alignment_efficiency,
+                        packing_efficiency)
 from .taskgraph import Access
+
+
+# ---------------------------------------------------------------------------
+# Kernel blocks: the Mosaic block rule and the VMEM they hold
+# ---------------------------------------------------------------------------
+def _block_iters(task: FusedTask, acc: Access) -> list[str] | None:
+    """The main-statement loops that shape ``acc``'s kernel block.
+
+    A fused pointwise statement keeps private iterators; the codegen runs
+    it on the main statement's output tile (epilogue fold), so its private
+    iterators map right-aligned onto the output's.  ``None`` when the
+    access cannot be mapped (broadcast dims, higher rank than the output).
+    """
+    main = task.main
+    if not acc.iters or any(it is None for it in acc.iters):
+        return None
+    out_iters = main.writes[0].iters
+    off = len(out_iters) - len(acc.iters)
+    mapped = []
+    for i, it in enumerate(acc.iters):
+        if it in main.loops:
+            mapped.append(it)
+        elif off + i >= 0:
+            mapped.append(out_iters[off + i])
+        else:
+            return None
+    return mapped
+
+
+def block_multiples(task: FusedTask) -> dict[str, int]:
+    """Per main loop, the multiple its tile must be — unless the tile is
+    the loop's full padded extent — for every kernel block it shapes to be
+    legal on the TPU (Mosaic): the last block dim a multiple of 128 lanes,
+    the second-to-last a multiple of 8 sublanes.  A rank-1 block needs 256
+    (128 lanes x the bf16 packing), whatever the dtype, so the rule does
+    not depend on which dtype a graph was traced at."""
+    need: dict[str, int] = {}
+    for s in task.statements:
+        for acc in tuple(s.reads) + tuple(s.writes):
+            its = _block_iters(task, acc)
+            if its is None:
+                continue
+            last = LANE * (2 if len(its) == 1 else 1)
+            need[its[-1]] = math.lcm(need.get(its[-1], 1), last)
+            if len(its) >= 2:
+                need[its[-2]] = math.lcm(need.get(its[-2], 1), SUBLANE)
+    return need
+
+
+def kernel_vmem_bytes(task: FusedTask, tiles) -> int:
+    """VMEM the task's contraction kernel holds for ``tiles``: every input
+    block double-buffered, the output block double-buffered, and the
+    accumulator scratch when the main statement reduces — each element at
+    4 bytes, since the kernels compute in f32.  Inputs are the arrays the
+    task reads from outside itself; the solver keeps this under the same
+    VMEM budget the kernels are compiled with
+    (``resources.VMEM_BYTES``)."""
+    main = task.main
+    produced = {w.array for s in task.statements for w in s.writes}
+
+    def elems(acc: Access) -> int:
+        its = _block_iters(task, acc) or [it for it in acc.iters if it]
+        n = 1
+        for it in its:
+            n *= tiles[it].tile
+        return n
+
+    seen: set[str] = set()
+    total = 0
+    for s in task.statements:
+        for acc in s.reads:
+            if acc.array in produced or acc.array in seen:
+                continue
+            seen.add(acc.array)
+            total += 2 * elems(acc)
+    out = elems(main.writes[0])
+    total += (3 if main.reduction_loops else 2) * out
+    return 4 * total
 
 
 # ---------------------------------------------------------------------------

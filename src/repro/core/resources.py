@@ -21,12 +21,19 @@ import dataclasses
 from typing import Sequence
 
 # ---------------------------------------------------------------------------
-# Roofline constants (assignment-specified for TPU v5e)
+# Roofline constants of one TPU v5e chip (Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s)
 # ---------------------------------------------------------------------------
+#: ``jax.Device.device_kind`` of the chip these constants describe; a TPU
+#: of any other kind must not be priced with them.
+TPU_KINDS = ("TPU v5 lite", "TPU v5e")
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s per chip
 HBM_BW = 819e9                    # bytes/s per chip
 ICI_BW = 50e9                     # bytes/s per link
-VMEM_BYTES = 16 * 2 ** 20         # usable VMEM per core (capacity constraint)
+# Scoped VMEM per kernel: the solver's capacity constraint AND the
+# ``vmem_limit_bytes`` every Pallas kernel is compiled with
+# (``repro.kernels.dispatch.compiler_params``) — one number for both.
+VMEM_BYTES = 16 * 2 ** 20
 VMEM_BW = 20 * HBM_BW             # on-chip buffer handoff bandwidth (VMEM)
 CLOCK_HZ = 940e6                  # nominal core clock (latency-term conversion)
 

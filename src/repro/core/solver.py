@@ -40,12 +40,13 @@ import sys
 import time
 
 from ..obs import tracer as _obs_tracer
-from .costmodel import (_access_of, footprint_elems, n_transfers,
-                        plan_latency, task_report)
+from .costmodel import (_access_of, block_multiples, footprint_elems,
+                        kernel_vmem_bytes, n_transfers, plan_latency,
+                        task_report)
 from .fusion import FusedGraph, FusedTask, fuse
 from .padding import TileOption, tile_options
 from .plan import ArrayPlacement, ExecutionPlan, TaskConfig, TaskReport
-from .resources import Hardware, THREE_SLICE, alignment_efficiency
+from .resources import TPU_KINDS, Hardware, THREE_SLICE, alignment_efficiency
 from .taskgraph import TaskGraph, legal_permutations
 
 
@@ -168,6 +169,7 @@ def _candidate_tiles(task: FusedTask, opts: SolverOptions) \
     tcs = task.trip_counts
     out: dict[str, list[TileOption]] = {}
     main = task.main
+    multiples = block_multiples(task)
     for loop in task.loops:
         tc = tcs[loop]
         if loop not in main.loops:
@@ -192,12 +194,28 @@ def _candidate_tiles(task: FusedTask, opts: SolverOptions) \
                     opts_l = [TileOption(1, tc, tc)]
             else:
                 opts_l = [TileOption(1, tc, tc)]
-            out[loop] = _prune_tiles(opts_l, tc, opts)
-            continue
-        max_pad = max(16, tc // 8) if caps.padding else 0
-        opts_l = tile_options(tc, max_pad=max_pad, max_tile=opts.max_tile)
-        out[loop] = _prune_tiles(opts_l, tc, opts)
+        else:
+            max_pad = max(16, tc // 8) if caps.padding else 0
+            opts_l = tile_options(tc, max_pad=max_pad, max_tile=opts.max_tile)
+        out[loop] = _legal_tiles(opts_l, tc, multiples.get(loop, 1), opts)
     return out
+
+
+def _legal_tiles(options: list[TileOption], tc: int, multiple: int,
+                 opts: SolverOptions) -> list[TileOption]:
+    """The mode's options whose blocks the TPU compiler accepts — a tile
+    that is a multiple of ``multiple`` or the loop's full padded extent
+    (the Mosaic block rule, ``costmodel.block_multiples``) — pruned to a
+    small menu.  Every mode gets the rule, so a graph has one plan whatever
+    kernel implementation runs it.  When none of the mode's options is
+    legal, the smallest legal tile stands in: ``multiple`` itself (the
+    extent padded up to it) or, for a shorter loop, its full extent."""
+    legal = [t for t in options
+             if t.tile % multiple == 0 or t.tile == t.padded_tc]
+    if not legal:
+        return [TileOption(multiple, -(-tc // multiple) * multiple, tc)
+                if tc > multiple else TileOption(tc, tc, tc)]
+    return _prune_tiles(legal, tc, opts)
 
 
 def _prune_tiles(options: list[TileOption], tc: int,
@@ -337,6 +355,8 @@ def _eval_combo(task: FusedTask, fg: FusedGraph, hw: Hardware,
     placements evaluated.  Shared verbatim by the serial sweep and the
     process-pool workers so both paths score identically."""
     sl = hw.slices[0]
+    if kernel_vmem_bytes(task, tiles) > sl.vmem:
+        return [], 0                  # the kernel's blocks overflow VMEM
     reads = task.read_arrays()
     overlap_opts = (True, False) if opts.caps.overlap else (False,)
     local: list[TaskChoice] = []
@@ -523,7 +543,26 @@ _WORKER_CTX: tuple | None = None
 
 def _pool_init(fg: FusedGraph, hw: Hardware, opts: SolverOptions) -> None:
     global _WORKER_CTX
+    # A chip belongs to one process: should anything in a worker import
+    # jax after all, it gets the CPU backend, never the parent's chip.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     _WORKER_CTX = (fg, hw, opts)
+
+
+def _w_jax_imported(_=None) -> bool:
+    return "jax" in sys.modules
+
+
+def sweep_workers_import_jax(workers: int = 2) -> bool:
+    """Start a sweep pool as :func:`solve` does and report whether any
+    worker has imported jax.  ``False`` means no worker can have started a
+    JAX backend, so none competes for the chip its parent holds."""
+    pool = _SweepPool(workers, None, None, None)
+    try:
+        futs = [pool.submit(_w_jax_imported) for _ in range(2 * workers)]
+        return any(f.result() for f in futs)
+    finally:
+        pool.shutdown()
 
 
 class _SweepPool:
@@ -754,13 +793,24 @@ def default_hardware(n_slices: int = 3) -> Hardware:
     """The board ``solve`` uses when the caller passes ``hw=None``: this
     host's cached calibrated profile (``repro.calibrate``) so slice and
     stream decisions answer to measured rates, falling back to the static
-    TPU constants when the host was never calibrated.  Never measures —
+    TPU v5e constants when the host was never calibrated.  Never measures —
     run ``scripts/calibrate.py`` (or ``repro.calibrate.calibrate()``) once
-    per host to materialize the profile."""
+    per host to materialize the profile.
+
+    Raises on a TPU whose ``device_kind`` is not a v5e: the static
+    constants would price it wrong.  A process that has not imported jax
+    holds no chip, and is not asked."""
     from ..calibrate import cached_hardware
     hw = cached_hardware(n_slices=n_slices)
     if hw is not None:
         return hw
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        dev = jax.devices()[0]
+        if dev.platform == "tpu" and dev.device_kind not in TPU_KINDS:
+            raise RuntimeError(
+                f"no hardware model for {dev.device_kind!r}: the static "
+                f"board describes {TPU_KINDS[0]!r} (core/resources.py)")
     return THREE_SLICE if n_slices == 3 else Hardware.make(n_slices=n_slices)
 
 
@@ -1008,6 +1058,8 @@ def _joint_choice(task: FusedTask, fg: FusedGraph, hw: Hardware,
     """Min-transfer placements, greedily demoted (next Pareto option:
     smaller buffer, more transfers) until the joint VMEM budget fits.
     Module-level (not a closure) so pool workers run it too."""
+    if kernel_vmem_bytes(task, tiles) > hw.slices[0].vmem:
+        return None
     reads = task.read_arrays()
     options: dict[str, list[ArrayPlacement]] = {}
     for a in reads:

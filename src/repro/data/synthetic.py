@@ -1,6 +1,6 @@
 """Deterministic synthetic LM data pipeline.
 
-Order-2 Markov token stream with a fixed transition structure: learnable
+Order-1 Markov token stream with a fixed transition structure: learnable
 (loss drops well below the uniform entropy) and fully reproducible per
 (seed, host, step), so elastic restarts re-produce the identical stream —
 the property the checkpoint-restart tests rely on.
@@ -27,25 +27,27 @@ class DataConfig:
         return self.global_batch // self.n_hosts
 
 
-def _transition(vocab: int, seed: int) -> np.ndarray:
-    """Sparse-ish row-stochastic transition over (prev token) -> token."""
+#: Share of each transition that follows the token's sparse successors; the
+#: rest is spread uniformly over the vocabulary.
+_SPARSE_MASS = 0.9
+
+
+def _transition(vocab: int, seed: int) -> tuple[np.ndarray, np.ndarray]:
+    """Row-stochastic (prev token) -> token transition, stored sparsely:
+    each token's ``k`` successors and the cumulative share of
+    ``_SPARSE_MASS`` each takes.  A dense (vocab, vocab) table would not fit
+    a host at a real model's vocabulary (151936^2 x 8 bytes = 185 GB)."""
     rng = np.random.default_rng(seed + 1234)
     k = min(8, vocab)
-    probs = np.full((vocab, vocab), 1e-9, np.float64)
-    for i in range(vocab):
-        nxt = rng.choice(vocab, size=k, replace=False)
-        w = rng.dirichlet(np.ones(k)) * 0.9
-        probs[i, nxt] += w
-        probs[i] += 0.1 / vocab
-    probs /= probs.sum(axis=1, keepdims=True)
-    return probs
+    succ = rng.integers(0, vocab, size=(vocab, k), dtype=np.int32)
+    cum = np.cumsum(rng.dirichlet(np.ones(k), size=vocab), axis=1)
+    return succ, cum * _SPARSE_MASS
 
 
 class SyntheticLM:
     def __init__(self, cfg: DataConfig):
         self.cfg = cfg
-        self._trans = _transition(cfg.vocab, cfg.seed)
-        self._cum = np.cumsum(self._trans, axis=1)
+        self._succ, self._cum = _transition(cfg.vocab, cfg.seed)
 
     def batch(self, step: int) -> tuple[np.ndarray, np.ndarray]:
         """(tokens, labels) of shape (host_batch, seq_len) int32."""
@@ -56,7 +58,11 @@ class SyntheticLM:
         toks = np.empty((b, s + 1), np.int32)
         toks[:, 0] = rng.integers(0, cfg.vocab, size=b)
         u = rng.random((b, s))
+        uniform = ((u - _SPARSE_MASS) / (1 - _SPARSE_MASS)
+                   * cfg.vocab).astype(np.int32).clip(0, cfg.vocab - 1)
         for t in range(s):
-            rows = self._cum[toks[:, t]]
-            toks[:, t + 1] = (rows > u[:, t:t + 1]).argmax(axis=1)
+            prev = toks[:, t]
+            j = (self._cum[prev] > u[:, t:t + 1]).argmax(axis=1)
+            toks[:, t + 1] = np.where(u[:, t] < _SPARSE_MASS,
+                                      self._succ[prev, j], uniform[:, t])
         return toks[:, :-1].copy(), toks[:, 1:].copy()

@@ -1,5 +1,4 @@
 """Distribution layer: sharding rules, pipeline parallelism, compression."""
 from . import compression, pipeline, sharding
-from .compat import shard_map_compat
 
-__all__ = ["compression", "pipeline", "sharding", "shard_map_compat"]
+__all__ = ["compression", "pipeline", "sharding"]

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map_compat
-
 
 def pipeline_apply(stage_fn: Callable, mesh: Mesh, axis: str,
                    stage_params, x_micro: jax.Array) -> jax.Array:
@@ -70,8 +68,8 @@ def pipeline_apply(stage_fn: Callable, mesh: Mesh, axis: str,
         return outs
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
-    return shard_map_compat(per_stage, mesh=mesh, in_specs=in_specs,
-                            out_specs=P())(stage_params, x_micro)
+    return jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)(stage_params, x_micro)
 
 
 def stage_assignment_cost(n_stages: int, n_micro: int,

@@ -2,8 +2,8 @@
 
 The paper's flow is source-to-source: unannotated affine code in, optimized
 accelerator program out.  This module is that front door for JAX: it walks a
-closed jaxpr (``pjit`` calls inlined, so ``jax.nn``-style jitted helpers are
-seen through) and lowers the **affine subset** to
+closed jaxpr (nested ``jit`` calls inlined, so ``jax.nn``-style jitted
+helpers are seen through) and lowers the **affine subset** to
 :class:`~repro.core.taskgraph.Statement` objects the solver/codegen stack
 already understands:
 
@@ -46,7 +46,7 @@ primitive                 lowering
                           iterators (rank-0 results fall back to opaque)
 ========================  =================================================
 
-``pjit``, ``custom_jvp_call`` and ``custom_vjp_call`` sub-jaxprs are
+Nested ``jit``, ``custom_jvp_call`` and ``custom_vjp_call`` sub-jaxprs are
 inlined (primal semantics), so ``jax.nn``-style helpers (relu/silu/gelu)
 are seen through.  Any floating dtype of at most 4 bytes is accepted —
 statements evaluate in f32 internally and the lowering records the
@@ -78,15 +78,11 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import Literal, Var
 
 from ..codegen.reference import OPAQUE_PREFIX, register_opaque
 from ..core.taskgraph import (Access, Statement, TaskGraph, copy_statement,
                               intermediate, iter_names)
-
-try:                       # jax >= 0.4.36 moved the jaxpr types here
-    from jax.extend.core import Literal, Var
-except ImportError:        # pragma: no cover - older jax
-    from jax.core import Literal, Var
 
 #: Pointwise primitives lowered to ``unary:<name>`` statements.
 UNARY_PRIMITIVES = ("tanh", "logistic", "exp", "log", "log1p", "expm1",
@@ -106,12 +102,12 @@ _FLOAT_OK = ("float32", "bfloat16", "float16")
 
 
 # ---------------------------------------------------------------------------
-# jaxpr flattening (pjit inlining)
+# jaxpr flattening (nested-jit inlining)
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass
 class FlatEqn:
     """One primitive application with its inputs resolved through every
-    inlined ``pjit`` boundary (invars are parent-scope atoms)."""
+    inlined nested-``jit`` boundary (invars are parent-scope atoms)."""
 
     eqn: Any                       # the original JaxprEqn
     invars: tuple[Any, ...]        # resolved atoms: Var | Literal
@@ -119,7 +115,7 @@ class FlatEqn:
 
 
 def flatten_jaxpr(jaxpr) -> tuple[list[FlatEqn], list[Any], dict]:
-    """Inline ``pjit`` sub-jaxprs into one flat equation list.
+    """Inline nested-``jit`` sub-jaxprs into one flat equation list.
 
     Returns ``(flat_eqns, resolved_outvars, sub_consts)`` where
     ``sub_consts`` maps sub-jaxpr constvars to their (structural) values —
@@ -151,14 +147,14 @@ def flatten_jaxpr(jaxpr) -> tuple[list[FlatEqn], list[Any], dict]:
 
     def walk(jx) -> None:
         for eqn in jx.eqns:
-            if eqn.primitive.name == "pjit":
+            if eqn.primitive.name == "jit":
                 if inline(eqn.params["jaxpr"], eqn):
                     continue
             elif eqn.primitive.name in ("custom_jvp_call",
                                         "custom_vjp_call"):
                 # Primal semantics: the call_jaxpr IS the function being
-                # differentiated — inline it exactly like a pjit body (the
-                # jvp/fwd/bwd rules only matter under differentiation,
+                # differentiated — inline it exactly like a nested-jit body
+                # (the jvp/fwd/bwd rules only matter under differentiation,
                 # which a traced executable never performs).
                 closed = eqn.params.get("call_jaxpr") \
                     or eqn.params.get("fun_jaxpr")

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..dispatch import compiler_params
 from .ref import apply_epilogue, combine_terms, project_term, scale_offset
 from .spec import ContractionSpec, Operand
 
@@ -57,12 +58,11 @@ def _make_kernel(spec: ContractionSpec):
             spec.init_coeff, spec.init_offset)
 
     def split(refs):
-        reads = [r[...].astype(jnp.float32) for r in refs[:n_reads]]
-        inits = [r[...].astype(jnp.float32)
-                 for r in refs[n_reads:n_reads + n_init]]
-        epis = [r[...].astype(jnp.float32)
-                for r in refs[n_reads + n_init:n_reads + n_init + n_epi]]
-        return reads, inits, epis, refs[n_reads + n_init + n_epi]
+        # blocks keep their dtype: combine_terms computes in f32 and picks
+        # the contraction's precision from the operands' dtype
+        vals = [r[...] for r in refs[:n_reads + n_init + n_epi]]
+        return (vals[:n_reads], vals[n_reads:n_reads + n_init],
+                vals[n_reads + n_init:], refs[n_reads + n_init + n_epi])
 
     def finish(total, inits, epis):
         """total -> stored value: scale, add init, run the fused tail."""
@@ -165,9 +165,6 @@ def build_contraction(spec: ContractionSpec, interpret: bool = False):
     kwargs = {}
     if has_scratch:
         kwargs["scratch_shapes"] = [pltpu.VMEM(spec.out_block, jnp.float32)]
-    if not interpret:
-        kwargs["compiler_params"] = _compiler_params(
-            _dimension_semantics(spec))
     return pl.pallas_call(
         body,
         grid=spec.grid,
@@ -175,19 +172,9 @@ def build_contraction(spec: ContractionSpec, interpret: bool = False):
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(spec.out_padded, jnp.float32),
         interpret=interpret,
+        compiler_params=compiler_params(_dimension_semantics(spec)),
         **kwargs,
     )
-
-
-def _compiler_params(sems: tuple[str, ...]):
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if cls is not None:
-        try:
-            return cls(dimension_semantics=sems)
-        except TypeError:
-            pass
-    return dict(mosaic=dict(dimension_semantics=sems))
 
 
 def contract(spec: ContractionSpec, *operands: jax.Array,

@@ -42,25 +42,23 @@ def _run_ref(spec: ContractionSpec, *operands: jax.Array) -> jax.Array:
     return ref.contract(spec, *operands)
 
 
-def _tracing() -> bool:
+def _tracing(operands) -> bool:
     """True when called under an enclosing trace (e.g. the whole-plan
     program jit) — the outer jit wrapper would only add a nested-jit layer
     with its own trace cache, so inline the raw computation instead."""
-    try:
-        return not jax.core.trace_state_clean()
-    except AttributeError:           # older/newer jax: assume top level
-        return False
+    return any(isinstance(a, jax.core.Tracer) for a in operands)
 
 
 def contract(spec: ContractionSpec, *operands: jax.Array,
              impl: str | None = None) -> jax.Array:
     """Evaluate ``spec`` on unpadded operands (reads then init_reads)."""
     impl = impl or dispatch.current_impl()
+    tracing = _tracing(operands)
     if impl == "xla":
-        if _tracing():
+        if tracing:
             return ref.contract(spec, *operands)
         return _run_ref(spec, *operands)
-    if _tracing():
+    if tracing:
         return _run_kernel.__wrapped__(spec, impl == "pallas_interpret",
                                        *operands)
     return _run_kernel(spec, impl == "pallas_interpret", *operands)

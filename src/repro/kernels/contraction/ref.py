@@ -102,11 +102,10 @@ def project_term(sub: str, out_sub: str, v: jax.Array,
     projection); output iterators absent from the operand are broadcast —
     the frontend's lowering of ``broadcast_in_dim`` and of size-1
     elementwise operands relies on this (einsum alone cannot introduce an
-    output label its inputs lack).
+    output label its inputs lack).  The projection runs in f32.
     """
     keep = "".join(c for c in out_sub if c in sub)
-    term = jnp.einsum(f"{sub}->{keep}", v,
-                      preferred_element_type=jnp.float32)
+    term = jnp.einsum(f"{sub}->{keep}", v.astype(jnp.float32))
     if keep != out_sub:
         missing = tuple(i for i, c in enumerate(out_sub) if c not in keep)
         term = jnp.broadcast_to(jnp.expand_dims(term, missing), out_shape)
@@ -121,6 +120,12 @@ def combine_terms(subs: list[str], out_sub: str, op: str,
     ``"sub"`` is the sum-of-projections with the first operand positive and
     every later operand negated (``a - b - c``) — the lowering of the
     elementwise ``sub``/``neg`` primitives.
+
+    Operands keep their own dtype and every result is f32.  A contraction
+    with an f32 operand runs in f32 at ``HIGHEST`` precision: the TPU's default
+    (in XLA and in Mosaic alike) multiplies f32 in one bf16 pass, which
+    would not compute the f32 statement.  Half-precision operands need
+    no more than the default pass.
     """
     if not vals:
         return jnp.zeros(zero_shape, jnp.float32)
@@ -139,10 +144,13 @@ def combine_terms(subs: list[str], out_sub: str, op: str,
             total = None
             for sub, v in zip(subs, vals):
                 term = project_term(sub, out_sub, v, zero_shape)
-                if term.dtype != jnp.float32:
-                    term = term.astype(jnp.float32)
                 total = term if total is None else total * term
             return total
+        if any(v.dtype == jnp.float32 for v in vals):
+            # one dtype for both sides: Mosaic refuses a mixed-dtype dot
+            return jnp.einsum(f"{','.join(subs)}->{out_sub}",
+                              *(v.astype(jnp.float32) for v in vals),
+                              precision=jax.lax.Precision.HIGHEST)
         return jnp.einsum(f"{','.join(subs)}->{out_sub}", *vals,
                           preferred_element_type=jnp.float32)
     total = None

@@ -18,6 +18,9 @@ import os
 import threading
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
+
+from ..core.resources import VMEM_BYTES
 
 _VALID = ("xla", "pallas_interpret", "pallas", "auto")
 _state = threading.local()
@@ -59,3 +62,11 @@ def use_pallas() -> bool:
 
 def interpret_mode() -> bool:
     return current_impl() == "pallas_interpret"
+
+
+def compiler_params(dimension_semantics=None) -> pltpu.CompilerParams:
+    """Mosaic parameters every kernel compiles with.  The scoped-VMEM limit
+    is the solver's VMEM budget (``core.resources.VMEM_BYTES``): one number
+    bounds both the plans the solver accepts and what a kernel may hold."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_BYTES)

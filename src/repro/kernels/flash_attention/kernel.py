@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..dispatch import compiler_params
+
 NEG_INF = -1e30
 
 
@@ -113,4 +115,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=compiler_params(),
     )(q, k, v)

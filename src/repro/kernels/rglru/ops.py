@@ -8,13 +8,16 @@ from .. import dispatch
 from . import kernel, ref
 
 
-def rglru(a: jax.Array, u: jax.Array, *, bs: int = 256,
+def rglru(a: jax.Array, u: jax.Array, *, bs: int | None = None,
           impl: str | None = None) -> jax.Array:
-    """h_t = a_t h_{t-1} + u_t over axis 1.  a, u: (B, S, D)."""
+    """h_t = a_t h_{t-1} + u_t over axis 1.  a, u: (B, S, D).  ``bs`` (the
+    sequence block) defaults to the largest that fits VMEM at this D."""
     impl = impl or dispatch.current_impl()
     if impl == "xla":
         return ref.rglru(a, u)
     b, s, d = a.shape
+    if bs is None:
+        bs = kernel.block_rows(d, a.dtype.itemsize)
     bs_ = min(bs, s)
     pad = (-s) % bs_
     if pad:

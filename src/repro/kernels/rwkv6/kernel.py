@@ -11,6 +11,8 @@ state matrix lives in a VMEM scratch carried across sequence blocks (grid is
 sequential over the S dimension).
 
 Layouts: r,k,w (BH, S, dk), v (BH, S, dv), u (BH, dk) -> y (BH, S, dv).
+``u`` rides as (BH, 1, dk) so its (1, 1, dk) block spans the array's last
+two dims, as Mosaic requires.
 """
 from __future__ import annotations
 
@@ -20,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..dispatch import compiler_params
 
 
 def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, st_ref, s_ref, *,
@@ -33,7 +37,7 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, st_ref, s_ref, *,
         k_t = k_ref[0, t, :].astype(jnp.float32)      # (dk,)
         v_t = v_ref[0, t, :].astype(jnp.float32)      # (dv,)
         w_t = w_ref[0, t, :].astype(jnp.float32)      # (dk,)
-        u = u_ref[0, :].astype(jnp.float32)           # (dk,)
+        u = u_ref[0, 0, :].astype(jnp.float32)        # (dk,)
         kv = k_t[:, None] * v_t[None, :]              # (dk, dv)
         y = jnp.sum((state + u[:, None] * kv) * r_t[:, None], axis=0)
         o_ref[0, t, :] = y.astype(o_ref.dtype)
@@ -64,7 +68,7 @@ def rwkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             pl.BlockSpec((1, bs, dk), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, bs, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, bs, dk), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, dk), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, dk), lambda i, j: (i, 0, 0)),
         ],
         out_specs=(pl.BlockSpec((1, bs, dv), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, dk, dv), lambda i, j: (i, 0, 0))),
@@ -72,4 +76,5 @@ def rwkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
                    jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u)
+        compiler_params=compiler_params(),
+    )(r, k, v, w, u[:, None, :])

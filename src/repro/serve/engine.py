@@ -70,11 +70,6 @@ class ServeConfig:
     eos_id: int | None = None
     seed: int = 0
     # -- plan-serving knobs (PlanEngine) ----------------------------------
-    # Persistent AOT compilation cache directory: replicas pointed at the
-    # same path share lowered XLA artifacts across processes, so a fresh
-    # replica's first compile deserializes instead of re-lowering.
-    # (env equivalent: REPRO_COMPILATION_CACHE_DIR)
-    compilation_cache_dir: str | None = None
     # Persistent plan store directory (repro.store): replicas pointed at
     # the same path share *solved plans* across processes, so a fresh
     # replica's register_function loads a fingerprint-keyed plan instead
@@ -163,9 +158,13 @@ class Engine:
         return jax.random.categorical(key, jnp.log(probs + 1e-9), axis=-1) \
             .astype(jnp.int32)
 
-    def generate(self, prompts: np.ndarray, max_new_tokens: int) \
-            -> np.ndarray:
-        """prompts (B, P) int32 -> (B, max_new_tokens) int32."""
+    def generate(self, prompts: np.ndarray, max_new_tokens: int, *,
+                 return_logits: bool = False):
+        """prompts (B, P) int32 -> (B, max_new_tokens) int32.
+
+        With ``return_logits`` also returns the logits each token was
+        sampled from, (B, max_new_tokens, V) float32: the prefill's for the
+        first token, then one decode step's for each next one."""
         b, p = prompts.shape
         assert p + max_new_tokens <= self.sc.max_len, "exceeds max_len"
         key = jax.random.PRNGKey(self.sc.seed)
@@ -173,10 +172,13 @@ class Engine:
             params=self.params, tokens=jnp.asarray(prompts),
             max_len=self.sc.max_len)
         out = np.zeros((b, max_new_tokens), np.int32)
+        seen: list[np.ndarray] = []
         done = np.zeros((b,), bool)
         key, sub = jax.random.split(key)
         tok = self._sample(logits, sub)
         for t in range(max_new_tokens):
+            if return_logits:
+                seen.append(np.asarray(logits))
             out[:, t] = np.where(done, 0, np.asarray(tok))
             if self.sc.eos_id is not None:
                 done |= np.asarray(tok) == self.sc.eos_id
@@ -186,6 +188,8 @@ class Engine:
                                          tokens=tok)
             key, sub = jax.random.split(key)
             tok = self._sample(logits, sub)
+        if return_logits:
+            return out, np.stack(seen, axis=1)
         return out
 
 
@@ -284,10 +288,9 @@ class PlanEngine:
     and every ``submit()`` is an O(1) keyed cache lookup — eviction-aware,
     so the cache's hit/eviction statistics stay the one source of truth.
 
-    ``ServeConfig`` carries the serving knobs: persistent AOT compilation
-    cache directory (cross-replica artifact sharing / warm start),
-    program-cache bound, executable-pool size, the registration admission
-    cap — and the resilience contract (deadlines, bounded in-flight depth,
+    ``ServeConfig`` carries the serving knobs: program-cache bound,
+    executable-pool size, the registration admission cap — and the
+    resilience contract (deadlines, bounded in-flight depth,
     canary validation, circuit breakers, background re-solve, chaos
     injection; see the module docstring).
 
@@ -300,11 +303,9 @@ class PlanEngine:
 
     def __init__(self, impl: str | None = None,
                  sc: ServeConfig | None = None):
-        from ..codegen import enable_persistent_cache, set_program_cache_size
+        from ..codegen import set_program_cache_size
         self._impl = impl
         self.sc = sc or ServeConfig()
-        if self.sc.compilation_cache_dir:
-            enable_persistent_cache(self.sc.compilation_cache_dir)
         if self.sc.plan_store_dir:
             from ..store import set_default_dir
             set_default_dir(self.sc.plan_store_dir)

@@ -14,6 +14,7 @@ import numpy as np
 from ..checkpoint import CheckpointManager
 from ..data import DataConfig, PrefetchLoader, SyntheticLM
 from ..ft import FailurePlan, run_with_restarts
+from ..launch.mesh import make_mesh
 from ..models import model as M
 from .optimizer import AdamWConfig, init_opt_state
 from .train_step import make_train_step
@@ -47,7 +48,7 @@ def train(cfg: M.ModelConfig, tc: TrainConfig,
     """Run training; returns (final TrainState, list of (step, loss))."""
     opt_cfg = opt_cfg or AdamWConfig(total_steps=tc.total_steps)
     if mesh is None:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
     key = jax.random.PRNGKey(tc.seed)
     params = M.init_params(cfg, key)
     opt_state = init_opt_state(params)

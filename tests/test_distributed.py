@@ -106,12 +106,12 @@ def test_gradient_compression_roundtrip():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro.distributed import compression as C, shard_map_compat
+        from repro.distributed import compression as C
         mesh = jax.make_mesh((4,), ('dp',))
         g = jax.random.normal(jax.random.PRNGKey(0), (4, 16, 32))
         def run(fn):
-            return shard_map_compat(fn, mesh=mesh, in_specs=P('dp'),
-                                    out_specs=P())(g)
+            return jax.shard_map(fn, mesh=mesh, in_specs=P('dp'),
+                                 out_specs=P(), check_vma=False)(g)
         mean_ref = np.asarray(jnp.mean(g, 0))
         out32 = run(lambda x: C.allreduce_mean({'g': x[0]}, 'dp')['g'])
         # psum may associate the 4-way sum differently than jnp.mean
@@ -139,7 +139,7 @@ def test_error_feedback_reduces_bias():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.distributed import compression as C, shard_map_compat
+        from repro.distributed import compression as C
         mesh = jax.make_mesh((4,), ('dp',))
         g = jax.random.normal(jax.random.PRNGKey(3), (4, 8, 8)) * \\
             jnp.logspace(-3, 0, 8)[None, None, :]   # ill-scaled rows
@@ -152,8 +152,8 @@ def test_error_feedback_reduces_bias():
                     m, e = C.allreduce_mean_int8_ef({'g': xs[0]}, e, 'dp')
                     acc = acc + m['g']
                 return acc / 8
-            return shard_map_compat(fn, mesh=mesh, in_specs=P('dp'),
-                                    out_specs=P())(x)
+            return jax.shard_map(fn, mesh=mesh, in_specs=P('dp'),
+                                 out_specs=P(), check_vma=False)(x)
         avg8 = np.asarray(run(g))
         one = np.asarray(run(g))  # deterministic
         err_avg = np.abs(avg8 - mean_ref).max()

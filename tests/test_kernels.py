@@ -214,3 +214,19 @@ def test_dispatch_rejects_bad_impl():
     with pytest.raises(ValueError):
         with kernel_impl("cuda"):
             pass
+
+
+# ---------------------------------------------------------------------------
+# contraction precision
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype, highest", [(jnp.float32, True),
+                                            (jnp.bfloat16, False)])
+def test_contraction_precision_follows_operand_dtype(dtype, highest):
+    """An f32 contraction asks for HIGHEST: the TPU's default multiplies
+    f32 in one bf16 pass.  bf16 operands keep the one-pass default."""
+    from repro.kernels.contraction.ref import combine_terms
+    a, b = jnp.ones((8, 16), dtype), jnp.ones((16, 4), dtype)
+    jaxpr = jax.make_jaxpr(lambda a, b: combine_terms(
+        ["ik", "kj"], "ij", "mul", [a, b], (8, 4)))(a, b)
+    assert "dot_general" in str(jaxpr)
+    assert ("Precision.HIGHEST" in str(jaxpr)) == highest

@@ -98,6 +98,18 @@ def test_synthetic_data_labels_are_shifted_tokens():
     np.testing.assert_array_equal(toks[:, 1:], labels[:, :-1])
 
 
+def test_synthetic_data_at_a_real_vocabulary():
+    """qwen3's 151936 tokens: the transition is held sparsely (a dense table
+    would take 185 GB of host memory) and still mostly follows it."""
+    cfg = DataConfig(vocab=151936, seq_len=64, global_batch=4, seed=0)
+    src = SyntheticLM(cfg)
+    toks, labels = src.batch(0)
+    assert toks.min() >= 0 and toks.max() < cfg.vocab
+    follows = np.mean([labels[b, t] in src._succ[toks[b, t]]
+                       for b in range(4) for t in range(64)])
+    assert 0.8 < follows < 0.98
+
+
 def test_prefetch_loader_order_and_seek():
     cfg = DataConfig(vocab=64, seq_len=8, global_batch=2, seed=0)
     src = SyntheticLM(cfg)
