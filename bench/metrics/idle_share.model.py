@@ -1,0 +1,10 @@
+"""idle_share.model: share of the traced window in which no operation ran
+on the device, in % (profiler trace: 1 - union of device-op intervals /
+window, averaged over the chips used)."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0 or t.busy_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
