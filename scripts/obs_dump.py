@@ -4,10 +4,9 @@ Produces, from one ``PlanEngine`` session with span tracing enabled:
 
 * a Chrome-trace / Perfetto JSON file (``--trace``) — load it at
   https://ui.perfetto.dev or ``chrome://tracing`` to see the request
-  path (admission/execute/fallback), the solver phases
-  (fuse/enumerate/chunk-merge), store load/save, the frontend trace,
-  and (with ``REPRO_OBS_SAMPLE``) sampled per-segment timings — one
-  virtual thread row per recording thread;
+  path (submit, admission, resolve, execute, sync, fallback), the solver
+  phases (fuse/enumerate/chunk-merge), store load/save and the frontend
+  trace — one virtual thread row per recording thread;
 * a Prometheus text-exposition file (``--metrics``) — the same numbers
   ``PlanEngine.stats()`` reports, in scrape format.
 

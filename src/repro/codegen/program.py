@@ -62,7 +62,6 @@ import jax
 from ..core.fusion import FusedGraph, fuse
 from ..core.plan import ExecutionPlan
 from ..core.taskgraph import TaskGraph
-from ..obs import profiler as _obs_profiler
 from .lower import TaskLowering, lower_task
 from .schedule import WaveSchedule, wave_schedule
 
@@ -481,9 +480,6 @@ class PlanProgram:
         the serving layer's entry (clone-attributed timing feeds the
         straggler monitor)."""
         clone = self._next_clone()
-        prof = _obs_profiler()
-        if prof.enabled and prof.should_sample(self.graph.name):
-            return self._run_profiled(inputs, self._pool[clone], prof), clone
         return self._run_on(inputs, self._pool[clone]), clone
 
     def _run_on(self, inputs: dict[str, jax.Array],
@@ -495,28 +491,6 @@ class PlanProgram:
         env = dict(inputs)
         for seg, fn in zip(self.segments, fns):
             res = fn(*[env[a] for a in seg.in_arrays])
-            env.update(zip(seg.out_arrays, res))
-        return {a: env[a] for a in self.out_names}
-
-    def _run_profiled(self, inputs: dict[str, jax.Array],
-                      fns: tuple[Callable, ...],
-                      prof) -> dict[str, jax.Array]:
-        """Sampled execution: segment-by-segment with a device sync after
-        each, so host clocks bracket real work (``REPRO_OBS_SAMPLE``).
-        The sync defeats async-dispatch pipelining, which is exactly why
-        this path is sampled instead of always-on."""
-        env = dict(inputs)
-        for seg, fn in zip(self.segments, fns):
-            seg_tids = set(seg.tids)
-            t0 = time.perf_counter()
-            res = fn(*[env[a] for a in seg.in_arrays])
-            jax.block_until_ready(res)
-            prof.record_segment(
-                self.graph.name, self.impl, seg.index,
-                time.perf_counter() - t0, n_tasks=len(seg.tids),
-                waves=tuple(n for n in (
-                    sum(1 for t in wave if t in seg_tids)
-                    for wave in self.schedule.waves) if n))
             env.update(zip(seg.out_arrays, res))
         return {a: env[a] for a in self.out_names}
 

@@ -1,15 +1,16 @@
 """repro.obs — observability for the serving/solver stack.
 
-* :mod:`repro.obs.trace`   — per-request spans in a bounded ring buffer,
-  exportable as Chrome-trace/Perfetto JSON (``scripts/obs_dump.py``).
+* :mod:`repro.obs.trace`   — per-request spans into a bounded ring buffer,
+  exportable as Chrome-trace/Perfetto JSON (``scripts/obs_dump.py``),
+  and into the JAX profiler's trace whenever a profiler session is
+  collecting (``jax.profiler.TraceAnnotation``, ``repro.<cat>/<name>``).
 * :mod:`repro.obs.metrics` — counters/gauges/histograms registry with
   Prometheus text exposition; backs ``PlanEngine.stats()``.
-* :mod:`repro.obs.profile` — ``REPRO_OBS_SAMPLE``-gated per-segment
-  timing inside ``PlanProgram`` execution.
 * :mod:`repro.obs.drift`   — cost-model predicted vs. observed latency
   EMA; drift triggers the background re-solve + plan-store refresh path.
 
-Everything here is stdlib-only (importable without jax).
+Everything here is stdlib-only (importable without jax; the profiler
+sink is looked up only once something else has imported jax).
 ``configure_logging()`` wires the ``repro`` logger family to the
 ``REPRO_LOG`` env level so background daemon threads (breaker re-solve,
 bucket presolve, stale plan refresh) leave a record instead of retrying
@@ -23,7 +24,6 @@ import os
 
 from .drift import DriftConfig, DriftDetector, DriftEvent
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry, default_registry
-from .profile import ProgramProfiler, configure_sampling, profiler
 from .trace import Span, Tracer, chrome_trace, configure, dump_chrome_trace, tracer
 
 __all__ = [
@@ -38,9 +38,6 @@ __all__ = [
     "configure",
     "chrome_trace",
     "dump_chrome_trace",
-    "ProgramProfiler",
-    "profiler",
-    "configure_sampling",
     "DriftConfig",
     "DriftDetector",
     "DriftEvent",

@@ -1,33 +1,58 @@
-"""Per-request span tracing into a bounded ring buffer.
+"""Per-request spans, to a bounded ring buffer and to the JAX profiler.
 
-Spans cover the whole request path (admission → queue wait → batch
-coalesce → execute → fallback/canary) plus solver phases (fusion,
-enumeration, chunk-merge) and sampled program segments.  Recording is
-lock-cheap: one short lock around a ``deque(maxlen=...)`` append, and a
-single ``enabled`` check on the fast path when tracing is off.
+Spans cover the whole request path (submit → admission → program lookup
+→ dispatch → timed sync → fallback/canary), the LM decode loop (prefill,
+then per token: sync, dispatch, sample), the background plan refresh and
+bucket pre-solve, and the solver phases (fusion, enumeration,
+chunk-merge).  Each span has two sinks:
 
-Export is Chrome-trace JSON (``chrome_trace()``), which Perfetto and
-``chrome://tracing`` both load directly; ``scripts/obs_dump.py`` writes
-it to disk.
+* the ring buffer, on with ``enabled`` (``REPRO_OBS_TRACE``): one short
+  lock around a ``deque(maxlen=...)`` append.  Export is Chrome-trace
+  JSON (``chrome_trace()``), which Perfetto and ``chrome://tracing``
+  both load directly; ``scripts/obs_dump.py`` writes it to disk.
+* the JAX profiler, whenever a profiler session is collecting: the span
+  is a ``jax.profiler.TraceAnnotation`` named ``repro.<cat>/<name>``
+  with its args as metadata, so it lands on the host plane of the same
+  ``.xplane.pb`` as the device operations, on the same clock.  The check
+  is made only once ``jax`` is imported, so this module never imports
+  jax itself (solver worker processes stay free of it).
 
-Span taxonomy (category / name):
+With both sinks off a span site returns one shared null span.
+``Tracer.record`` (a span measured after the fact, such as the
+batcher's queue wait) reaches the ring buffer only: the profiler takes
+no retroactive events.
 
+Span taxonomy (category / name; ``rid`` ties the spans of one
+``PlanEngine.submit`` together, ``gid`` and ``t`` those of one
+``Engine.generate`` and its tokens):
+
+* ``request/submit``      — the whole of ``PlanEngine.submit``
 * ``request/admission``   — semaphore wait + deadline check in ``submit``
-* ``request/queue_wait``  — batcher enqueue → flush pick-up
-* ``request/batch_coalesce`` — stacking + batched submit of one bucket
-* ``request/execute``     — optimized program run (one clone dispatch)
+* ``request/resolve``     — compiled-program lookup (``miss``: built and
+  compiled on this request)
+* ``request/execute``     — optimized program dispatch (one clone)
+* ``request/sync``        — device sync of a timed run (``reason``:
+  ``drift``, ``canary``, ``straggler`` or ``nan_guard``)
 * ``request/fallback``    — plain-jit fallback run
-* ``request/canary``      — canary validation of a rebuilt program
+* ``request/canary``      — canary validation of the optimized answer
+* ``request/queue_wait``  — batcher enqueue → flush pick-up (ring only)
+* ``request/batch_coalesce`` — stacking + batched submit of one bucket
+* ``generate/prefill``    — prefill dispatch and the first sample
+* ``decode/token``        — one token of the decode loop, parent of
+  ``decode/sync`` (waiting for the sampled token on the host),
+  ``decode/dispatch`` (the jitted decode step) and ``decode/sample``
+* ``plan/refresh``        — background drift/stale re-solve loop
+* ``plan/presolve``       — background batch-bucket pre-solve
 * ``solver/fuse``, ``solver/enumerate``, ``solver/chunk_merge``
 * ``store/load``, ``store/save``
 * ``frontend/trace``      — jaxpr capture + lowering
-* ``profile/segment``     — sampled per-segment timing (obs/profile.py)
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -53,7 +78,7 @@ class Span:
 
 
 class _NullSpan:
-    """No-op context manager returned when tracing is disabled."""
+    """No-op context manager returned when both sinks are off."""
 
     __slots__ = ()
 
@@ -69,35 +94,63 @@ class _NullSpan:
 
 _NULL = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation`` once jax is imported.
+_annotation = None
+
+
+def _profiler_annotation():
+    """``TraceAnnotation`` while a JAX profiler session is collecting,
+    else ``None``.  Never imports jax: until something else has, there
+    is no profiler to collect."""
+    global _annotation
+    ann = _annotation
+    if ann is None:
+        if sys.modules.get("jax") is None:
+            return None
+        from jax.profiler import TraceAnnotation as ann
+        _annotation = ann
+    return ann if ann.is_enabled() else None
+
 
 class _LiveSpan:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
+    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict,
+                 annotation):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = None if annotation is None \
+            else annotation(f"repro.{cat}/{name}", **args)
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, etype, exc, tb):
         t1 = time.perf_counter()
         if etype is not None:
-            self.args.setdefault("error", etype.__name__)
-        self._tracer.record(self.name, self.cat, self._t0, t1 - self._t0, self.args)
+            self.set(error=etype.__name__)
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
+        self._tracer.record(self.name, self.cat, self._t0, t1 - self._t0,
+                            self.args)
         return False
 
     def set(self, **kw):
         self.args.update(kw)
+        if self._ann is not None:
+            self._ann.set_metadata(**kw)
         return self
 
 
 class Tracer:
-    """Bounded span recorder.  ``enabled`` flips the whole thing off at
-    the cost of one attribute read per span site."""
+    """Bounded span recorder.  With ``enabled`` off and no profiler
+    session collecting, a span site costs one attribute read and one
+    profiler check."""
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY, enabled: bool | None = None):
         if enabled is None:
@@ -110,15 +163,17 @@ class Tracer:
 
     # -- recording ------------------------------------------------------
     def span(self, name: str, cat: str = "request", **args):
-        """Context manager timing a block; no-op when disabled."""
-        if not self.enabled:
+        """Context manager timing a block into each sink that is on."""
+        ann = _profiler_annotation()
+        if not self.enabled and ann is None:
             return _NULL
-        return _LiveSpan(self, name, cat, args)
+        return _LiveSpan(self, name, cat, args, ann)
 
     def record(self, name: str, cat: str, start_s: float, dur_s: float,
                args: dict | None = None) -> None:
-        """Record a completed span (used for queue waits measured after
-        the fact, where a context manager can't straddle threads)."""
+        """Record a completed span in the ring buffer (used for queue
+        waits measured after the fact, where a context manager can't
+        straddle threads; the profiler takes no retroactive events)."""
         if not self.enabled:
             return
         sp = Span(name, cat, start_s, dur_s, threading.get_ident(),

@@ -41,10 +41,10 @@ and two branch checks.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import threading
 import time
-from functools import partial
 from typing import Any
 
 import jax
@@ -147,9 +147,19 @@ class Engine:
         self.cfg = cfg
         self.params = params
         self.sc = sc or ServeConfig()
-        self._prefill = jax.jit(partial(M.prefill, cfg=self.cfg),
-                                static_argnames=("max_len",))
-        self._decode = jax.jit(partial(M.decode_step, cfg=self.cfg))
+
+        # named functions, not partials: the profiler trace and the HLO
+        # name the programs jit_prefill and jit_decode_step
+        def prefill(params, tokens, max_len):
+            return M.prefill(params, cfg, tokens, max_len=max_len)
+
+        def decode_step(params, cache, tokens):
+            return M.decode_step(params, cfg, cache, tokens)
+
+        self._prefill = jax.jit(prefill, static_argnames=("max_len",))
+        self._decode = jax.jit(decode_step)
+        self._tr = _obs_tracer()
+        self._gids = itertools.count()
 
     def _sample(self, logits: jax.Array, key) -> jax.Array:
         if self.sc.temperature <= 0.0:
@@ -167,27 +177,35 @@ class Engine:
         first token, then one decode step's for each next one."""
         b, p = prompts.shape
         assert p + max_new_tokens <= self.sc.max_len, "exceeds max_len"
+        span = self._tr.span
+        gid = next(self._gids)
         key = jax.random.PRNGKey(self.sc.seed)
-        logits, cache = self._prefill(
-            params=self.params, tokens=jnp.asarray(prompts),
-            max_len=self.sc.max_len)
+        with span("prefill", "generate", gid=gid, batch=b):
+            logits, cache = self._prefill(
+                params=self.params, tokens=jnp.asarray(prompts),
+                max_len=self.sc.max_len)
+            key, sub = jax.random.split(key)
+            tok = self._sample(logits, sub)
         out = np.zeros((b, max_new_tokens), np.int32)
         seen: list[np.ndarray] = []
         done = np.zeros((b,), bool)
-        key, sub = jax.random.split(key)
-        tok = self._sample(logits, sub)
         for t in range(max_new_tokens):
-            if return_logits:
-                seen.append(np.asarray(logits))
-            out[:, t] = np.where(done, 0, np.asarray(tok))
-            if self.sc.eos_id is not None:
-                done |= np.asarray(tok) == self.sc.eos_id
-                if done.all():
-                    break
-            logits, cache = self._decode(params=self.params, cache=cache,
-                                         tokens=tok)
-            key, sub = jax.random.split(key)
-            tok = self._sample(logits, sub)
+            with span("token", "decode", gid=gid, t=t, batch=b):
+                with span("sync", "decode", gid=gid, t=t, batch=b):
+                    if return_logits:
+                        seen.append(np.asarray(logits))
+                    host_tok = np.asarray(tok)
+                out[:, t] = np.where(done, 0, host_tok)
+                if self.sc.eos_id is not None:
+                    done |= host_tok == self.sc.eos_id
+                    if done.all():
+                        break
+                with span("dispatch", "decode", gid=gid, t=t, batch=b):
+                    logits, cache = self._decode(params=self.params,
+                                                 cache=cache, tokens=tok)
+                with span("sample", "decode", gid=gid, t=t, batch=b):
+                    key, sub = jax.random.split(key)
+                    tok = self._sample(logits, sub)
         if return_logits:
             return out, np.stack(seen, axis=1)
         return out
@@ -351,6 +369,14 @@ class PlanEngine:
         self._c_drift_triggers = m.counter(
             "repro_drift_triggers_total",
             "cost-model drift events that triggered a plan refresh")
+        self._c_program_builds = m.counter(
+            "repro_program_builds_total",
+            "program lookups that missed and built (compiled) on the "
+            "request path")
+        self._c_syncs = m.counter(
+            "repro_request_syncs_total",
+            "optimized runs synced with the device before returning",
+            ("reason",))
         self._g_inflight = m.gauge(
             "repro_inflight", "requests currently admitted")
         self._h_latency = m.histogram(
@@ -382,7 +408,11 @@ class PlanEngine:
             threading.BoundedSemaphore(self.sc.max_inflight)
             if self.sc.max_inflight else None)
         self._stop = threading.Event()
+        # injectable time: breaker resets, timed optimized runs, and the
+        # injected chaos delay (tests make both deterministic)
         self._clock = time.monotonic
+        self._sleep = time.sleep
+        self._rids = itertools.count()
         # background plan-refresh / bucket-presolve threads (stale store
         # hits, register-time bucket pre-solving) — joined in shutdown()
         self._bg_threads: list[threading.Thread] = []
@@ -555,34 +585,36 @@ class PlanEngine:
                 mult=self.sc.resolve_backoff_mult,
                 max_s=self.sc.resolve_backoff_max_s,
                 retries=self.sc.resolve_max_retries)
-            try:
-                for attempt, delay in enumerate(policy.delays(), start=1):
-                    if self._stop.wait(delay):
-                        return
-                    with self._lock:
-                        if name not in self._registry:
-                            return      # unregistered while refreshing
-                    try:
-                        chaos = self.sc.chaos
-                        if chaos is not None:
-                            chaos.on_refresh(name)
-                        self._rebuild(name, impl)
-                    except Exception as exc:
+            with self._tr.span("refresh", "plan", entry=name):
+                try:
+                    for attempt, delay in enumerate(policy.delays(), start=1):
+                        if self._stop.wait(delay):
+                            return
+                        with self._lock:
+                            if name not in self._registry:
+                                return  # unregistered while refreshing
+                        try:
+                            chaos = self.sc.chaos
+                            if chaos is not None:
+                                chaos.on_refresh(name)
+                            self._rebuild(name, impl)
+                        except Exception as exc:
+                            log.info(
+                                "plan-refresh entry=%s attempt=%d "
+                                "backoff_s=%.3f failed: %s",
+                                name, attempt, delay, exc)
+                            continue
+                        self._c_plan_refreshes.inc()
                         log.info(
-                            "plan-refresh entry=%s attempt=%d "
-                            "backoff_s=%.3f failed: %s",
-                            name, attempt, delay, exc)
-                        continue
-                    self._c_plan_refreshes.inc()
-                    log.info(
-                        "plan-refresh entry=%s attempt=%d succeeded: "
-                        "stale plan refreshed in background", name, attempt)
-                    return
-                log.warning("plan-refresh entry=%s gave up after %d "
-                            "attempts", name, self.sc.resolve_max_retries)
-            finally:
-                with self._lock:
-                    self._refreshing.discard(name)
+                            "plan-refresh entry=%s attempt=%d succeeded: "
+                            "stale plan refreshed in background",
+                            name, attempt)
+                        return
+                    log.warning("plan-refresh entry=%s gave up after %d "
+                                "attempts", name, self.sc.resolve_max_retries)
+                finally:
+                    with self._lock:
+                        self._refreshing.discard(name)
 
         t = threading.Thread(target=_loop, daemon=True,
                              name=f"repro-plan-refresh-{name}")
@@ -597,7 +629,8 @@ class PlanEngine:
 
         def _loop():
             try:
-                n = self.batcher().presolve(name, stop=self._stop)
+                with self._tr.span("presolve", "plan", entry=name):
+                    n = self.batcher().presolve(name, stop=self._stop)
             except Exception as exc:
                 log.info("bucket-presolve entry=%s failed: %s", name, exc)
                 return
@@ -708,26 +741,29 @@ class PlanEngine:
                        for attr, fam in self._entry_families.items()})
             return health
 
-    def _resolve(self, name: str, impl: str):
+    def _resolve(self, name: str, impl: str, rid: int):
         from ..codegen import compiled_program, program_cache, program_key
-        with self._lock:
-            key = self._keys.get((name, impl))
-            if key is None:
-                graph, plan = self._registry[name]
-                key = program_key(graph, plan, impl)
-                self._keys[(name, impl)] = key
-            else:
-                graph, plan = self._registry[name]
-        # fast path: an O(1) keyed hit honouring this engine's pool
-        # contract (a pool-mismatched entry is NOT counted as a hit —
-        # compiled_program rebuilds and re-admits it below)
-        prog = program_cache().get_if(key, self.sc.pool_size)
-        if prog is not None:
-            return prog
-        # miss or evicted or foreign pool: build once (per-key build lock
-        # inside compiled_program), re-admitted as MRU
-        return compiled_program(graph, plan, impl,
-                                pool_size=self.sc.pool_size)
+        with self._tr.span("resolve", "request", entry=name, rid=rid) as sp:
+            with self._lock:
+                key = self._keys.get((name, impl))
+                if key is None:
+                    graph, plan = self._registry[name]
+                    key = program_key(graph, plan, impl)
+                    self._keys[(name, impl)] = key
+                else:
+                    graph, plan = self._registry[name]
+            # fast path: an O(1) keyed hit honouring this engine's pool
+            # contract (a pool-mismatched entry is NOT counted as a hit —
+            # compiled_program rebuilds and re-admits it below)
+            prog = program_cache().get_if(key, self.sc.pool_size)
+            sp.set(miss=prog is None)
+            if prog is not None:
+                return prog
+            # miss or evicted or foreign pool: build once (per-key build
+            # lock inside compiled_program), re-admitted as MRU
+            self._c_program_builds.inc()
+            return compiled_program(graph, plan, impl,
+                                    pool_size=self.sc.pool_size)
 
     def batcher(self) -> Batcher:
         """The engine's continuous-batching front door (lazily started on
@@ -791,38 +827,41 @@ class PlanEngine:
         that served the request.
         """
         t0 = time.monotonic()
-        deadline = deadline_s if deadline_s is not None \
-            else self.sc.deadline_s
-        sem = self._inflight_sem
-        if sem is not None:
-            timeout = self.sc.admission_timeout_s
-            if deadline is not None:
-                timeout = min(timeout, deadline)
-            with self._tr.span("admission", "request", entry=name):
-                admitted = sem.acquire(timeout=max(0.0, timeout))
-            if not admitted:
-                if deadline is not None \
-                        and time.monotonic() - t0 >= deadline:
-                    self._c_deadline_rejected.inc()
-                    raise DeadlineExceeded(
-                        f"{name}: deadline {deadline:.3f}s expired before "
-                        "admission (engine at max_inflight="
-                        f"{self.sc.max_inflight})")
-                self._c_rejected.inc()
-                raise EngineOverloaded(
-                    f"{name}: {self.sc.max_inflight} requests in flight; "
-                    f"none drained within {timeout:.3f}s")
-        try:
-            self._g_inflight.inc()
-            return self._submit_admitted(name, inputs, t0, deadline,
-                                         _info)
-        finally:
-            self._g_inflight.dec()
+        rid = next(self._rids)
+        with self._tr.span("submit", "request", entry=name, rid=rid):
+            deadline = deadline_s if deadline_s is not None \
+                else self.sc.deadline_s
+            sem = self._inflight_sem
             if sem is not None:
-                sem.release()
+                timeout = self.sc.admission_timeout_s
+                if deadline is not None:
+                    timeout = min(timeout, deadline)
+                with self._tr.span("admission", "request", entry=name,
+                                   rid=rid):
+                    admitted = sem.acquire(timeout=max(0.0, timeout))
+                if not admitted:
+                    if deadline is not None \
+                            and time.monotonic() - t0 >= deadline:
+                        self._c_deadline_rejected.inc()
+                        raise DeadlineExceeded(
+                            f"{name}: deadline {deadline:.3f}s expired "
+                            "before admission (engine at max_inflight="
+                            f"{self.sc.max_inflight})")
+                    self._c_rejected.inc()
+                    raise EngineOverloaded(
+                        f"{name}: {self.sc.max_inflight} requests in "
+                        f"flight; none drained within {timeout:.3f}s")
+            try:
+                self._g_inflight.inc()
+                return self._submit_admitted(name, inputs, t0, deadline,
+                                             rid, _info)
+            finally:
+                self._g_inflight.dec()
+                if sem is not None:
+                    sem.release()
 
     def _submit_admitted(self, name: str, inputs, t0: float,
-                         deadline: float | None,
+                         deadline: float | None, rid: int,
                          _info: dict | None = None) -> Any:
         impl = self._current_impl()
         with self._lock:
@@ -845,7 +884,7 @@ class PlanEngine:
             try:
                 out = self._run_optimized(
                     name, impl, tf, env if env is not None else inputs,
-                    health)
+                    health, rid)
             except Exception as exc:
                 self._note_failure(name, impl, health, exc)
                 if not self.sc.fallback:
@@ -861,7 +900,7 @@ class PlanEngine:
                 if env is not None:
                     return tf.unbind(out, env)
                 return out
-        with self._tr.span("fallback", "request", entry=name):
+        with self._tr.span("fallback", "request", entry=name, rid=rid):
             out = self._run_fallback(name, tf, env, inputs, health)
         self._note_deadline(t0, deadline, health)
         self._h_latency.labels("fallback").observe(time.monotonic() - t0)
@@ -870,13 +909,13 @@ class PlanEngine:
         return out
 
     def _run_optimized(self, name: str, impl: str, tf, env: dict,
-                       health: _EntryHealth) -> dict:
+                       health: _EntryHealth, rid: int) -> dict:
         """The one-dispatch path; raises on any failure (compile, execute,
         injected chaos, NaN guard, canary mismatch)."""
         chaos = self.sc.chaos
         if chaos is not None:
             chaos.on_compile(name)
-        prog = self._resolve(name, impl)
+        prog = self._resolve(name, impl, rid)
         if chaos is not None:
             chaos.on_execute(name)
         attempt = health.attempts.inc() - 1
@@ -887,33 +926,39 @@ class PlanEngine:
         # the steady-state path
         drift_sample = self._drift.config.enabled \
             and self._drift.should_sample(name)
-        timed = canary or (self.sc.straggler is not None
-                           and prog.pool_size > 1) \
-            or self.sc.nan_guard == "always" or drift_sample
-        t_run = time.monotonic()
-        with self._tr.span("execute", "request", entry=name) as sp:
+        straggler = self.sc.straggler is not None and prog.pool_size > 1
+        nan_always = self.sc.nan_guard == "always"
+        t_run = self._clock()
+        with self._tr.span("execute", "request", entry=name,
+                           rid=rid) as sp:
             out, clone = prog.run(env)
             if chaos is not None:
                 delay = chaos.execute_delay(name, clone)
                 if delay > 0.0:
-                    time.sleep(delay)
+                    self._sleep(delay)
                 out = chaos.corrupt_outputs(name, out)
-            if timed:
+            sp.set(clone=clone)
+        # why this run waits for the device, if it does
+        reason = ("drift" if drift_sample else "canary" if canary
+                  else "straggler" if straggler
+                  else "nan_guard" if nan_always else None)
+        if reason is not None:
+            self._c_syncs.labels(reason).inc()
+            with self._tr.span("sync", "request", entry=name, rid=rid,
+                               reason=reason):
                 jax.block_until_ready(list(out.values()))
-            sp.set(clone=clone, timed=timed)
-        elapsed = time.monotonic() - t_run
+        elapsed = self._clock() - t_run
         if drift_sample:
             self._note_drift(name, elapsed)
-        if self.sc.straggler is not None and prog.pool_size > 1:
+        if straggler:
             self._observe_clone(name, health, prog, clone, elapsed)
-        guard_nan = self.sc.nan_guard == "always" \
-            or (canary and self.sc.nan_guard == "canary")
+        guard_nan = nan_always or (canary and self.sc.nan_guard == "canary")
         if canary:
             health.canaries.inc()
         if guard_nan:
             self._guard_finite(name, out)
         if canary:
-            with self._tr.span("canary", "request", entry=name):
+            with self._tr.span("canary", "request", entry=name, rid=rid):
                 self._validate_canary(name, tf, env, out, health)
         return out
 

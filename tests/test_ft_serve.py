@@ -426,6 +426,16 @@ def test_slow_clone_is_rotated_out_of_round_robin():
         pool_size=2, chaos=chaos,
         straggler=StragglerConfig(threshold=1.5, patience=2, min_steps=1,
                                   ema=0.5)))
+    # The monitor sees each run's time on the engine's clock.  On the wall
+    # clock, warm-up's first run of clone 0 includes its compile (0.2 s
+    # alone, longer under parallel test workers): that seeds clone 0's
+    # EMA so high that the 50 ms clone stays under 1.5x it for most of
+    # the eight submits, and is flagged twice in a row only when the
+    # compile was quick.  On a clock that only the injected delay moves,
+    # clone 1 reads 50 ms and clone 0 nothing, every time.
+    now = [0.0]
+    eng._clock = lambda: now[0]
+    eng._sleep = lambda s: now.__setitem__(0, now[0] + s)
     eng.register("m", g, plan)
     eng.warmup("m", ins)
     for _ in range(8):

@@ -8,6 +8,10 @@ What these pin down:
   ``completed+expired+errors == enqueued``) hold under threaded chaos;
 * the span tracer is bounded (ring buffer drops, never grows) and its
   Chrome-trace export is loadable JSON with microsecond complete events;
+* spans reach the JAX profiler's trace while a profiler session is
+  collecting, nested and tagged per request (``rid``) and per token
+  (``gid``, ``t``); with both sinks off a span site is the null span, and
+  ``repro.obs`` imports without jax;
 * drift detection is deterministic on an injected clock: min-samples,
   threshold band (both directions), cooldown, and EMA reset on re-plan;
 * ``stats()`` never deadlocks against a concurrent submit storm — the
@@ -15,9 +19,14 @@ What these pin down:
 """
 from __future__ import annotations
 
+import glob
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
+from types import SimpleNamespace as NS
 
 import jax
 import jax.numpy as jnp
@@ -26,8 +35,8 @@ import pytest
 
 from repro.core import SolverOptions
 from repro.ft import ChaosPlan
-from repro.obs import (DriftConfig, DriftDetector, MetricsRegistry,
-                       ProgramProfiler, Tracer, chrome_trace)
+from repro.obs import (DriftConfig, DriftDetector, MetricsRegistry, Tracer,
+                       chrome_trace)
 from repro.serve import BatchConfig, PlanEngine, ServeConfig
 
 _RNG = np.random.default_rng(0)
@@ -266,25 +275,6 @@ def test_drift_stats_shape():
 
 
 # ---------------------------------------------------------------------------
-# Program profiler
-# ---------------------------------------------------------------------------
-def test_profiler_sampling_cadence_and_aggregation():
-    p = ProgramProfiler(sample_every=3)
-    assert p.enabled
-    hits = [p.should_sample("prog") for _ in range(9)]
-    assert hits == [False, False, True] * 3     # one in three, per key
-    p.record_segment("prog", "xla", 0, 0.5, n_tasks=2, waves=(1, 1))
-    p.record_segment("prog", "xla", 0, 1.5, n_tasks=2, waves=(1, 1))
-    seg = p.stats()["programs"]["prog"]["xla"][0]
-    assert seg["count"] == 2
-    assert seg["mean_s"] == pytest.approx(1.0)
-    assert seg["min_s"] == 0.5 and seg["max_s"] == 1.5
-    p.clear()
-    assert p.stats()["programs"] == {}
-    assert not ProgramProfiler(sample_every=0).should_sample("prog")
-
-
-# ---------------------------------------------------------------------------
 # Engine integration: registry is the single source of truth
 # ---------------------------------------------------------------------------
 def test_engine_stats_exposition_and_invariants_agree():
@@ -446,3 +436,144 @@ def test_stats_never_deadlocks_against_submit_storm():
         assert eng.requests > 0
     finally:
         eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# Profiler sink: spans on the JAX profiler's clock
+# ---------------------------------------------------------------------------
+def _profiled(tmp_path, fn) -> list:
+    """Run ``fn`` under a JAX profiler session; the trace's ``repro.*``
+    host events, with the thread row each is on."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for k, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    out.append(NS(name=ev.name[len("repro."):],
+                                  row=(plane.name, k), start=ev.start_ns,
+                                  end=ev.start_ns + ev.duration_ns,
+                                  args=dict(ev.stats)))
+    return out
+
+
+def _inside(child, parent) -> bool:
+    return child.row == parent.row and parent.start <= child.start \
+        and child.end <= parent.end
+
+
+def test_span_site_is_null_with_both_sinks_off(tmp_path):
+    from repro.obs import trace as T
+    t = Tracer(capacity=4, enabled=False)
+    assert t.span("x", "test", k=1) is T._NULL
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sp = t.span("x", "test", k=1)     # the profiler alone makes it live
+        with sp:
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert sp is not T._NULL
+    assert t.snapshot() == []             # ... and the ring stays off
+    assert t.span("x", "test") is T._NULL
+
+
+def test_obs_imports_without_jax():
+    """Solver worker processes import ``repro.obs`` through the solver;
+    neither the import nor a span site may pull jax in."""
+    import repro.obs
+    src = os.path.dirname(os.path.dirname(os.path.dirname(
+        repro.obs.__file__)))
+    code = "\n".join([
+        "import sys",
+        "sys.modules['jax'] = None",      # any import of jax now fails
+        "import repro.obs as o",
+        "t = o.Tracer(enabled=True)",
+        "with t.span('x', 'test', k=1) as sp:",
+        "    sp.set(j=2)",
+        "assert [s.name for s in t.snapshot()] == ['x']",
+        "assert o.Tracer(enabled=False).span('y') is o.trace._NULL",
+        "assert not [m for m, v in sys.modules.items()",
+        "            if v is not None and m.split('.')[0] == 'jax']",
+    ])
+    env = {k: v for k, v in os.environ.items() if k != "REPRO_OBS_TRACE"}
+    env["PYTHONPATH"] = src
+    subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                   timeout=120)
+
+
+def test_plan_submit_spans_reach_the_profiler_trace(tmp_path):
+    """Two submits under a profiler session: the first builds its
+    program and is canary-sampled (a timed run), the second is a warm
+    dispatch.  Every span of a submit carries its ``rid`` and nests in
+    its ``request/submit`` on the same thread."""
+    from repro.codegen import clear_program_cache
+    clear_program_cache()
+    eng = _engine(ServeConfig(canary_every=2,
+                              drift=DriftConfig(enabled=False)))
+    try:
+        spans = _profiled(tmp_path, lambda: [eng.submit("f", (_X,))
+                                             for _ in range(2)])
+    finally:
+        eng.shutdown()
+    subs = sorted((s for s in spans if s.name == "request/submit"),
+                  key=lambda s: s.start)
+    assert len(subs) == 2 and subs[0].args["rid"] != subs[1].args["rid"]
+    kids = []
+    for sub in subs:
+        mine = [s for s in spans if s is not sub
+                and s.args.get("rid") == sub.args["rid"]]
+        assert all(_inside(s, sub) for s in mine)
+        assert all(s.args["entry"] == "f" for s in mine + [sub])
+        kids.append({s.name: s for s in mine})
+    cold, warm = kids
+    assert set(cold) == {"request/resolve", "request/execute",
+                         "request/sync", "request/canary"}
+    assert set(warm) == {"request/resolve", "request/execute"}
+    assert cold["request/resolve"].args["miss"] == 1
+    assert warm["request/resolve"].args["miss"] == 0
+    assert cold["request/sync"].args["reason"] == "canary"
+    # dispatch only: the timed run's sync follows the execute span
+    assert cold["request/execute"].end <= cold["request/sync"].start
+    assert eng.metrics.value("repro_program_builds_total") == 1
+    assert eng.metrics.value("repro_request_syncs_total", "canary") == 1
+
+
+def test_generate_spans_reach_the_profiler_trace(tmp_path):
+    """One ``Engine.generate`` of 3 tokens: a prefill span, then one
+    ``decode/token`` per token, each the parent of one sync, one
+    dispatch and one sample, all tagged with the call's ``gid``."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import smoke
+    from repro.models import model as M
+    from repro.serve.engine import Engine
+    cfg = dataclasses.replace(smoke(get_config("qwen3-0.6b")),
+                              compute_dtype="float32",
+                              kv_cache_dtype="float32")
+    eng = Engine(cfg, M.init_params(cfg, jax.random.PRNGKey(0)),
+                 ServeConfig(max_len=16))
+    prompts = np.array([[1, 2, 3, 4], [5, 6, 7, 8]], np.int32)
+    eng.generate(prompts, 3)              # compiles outside the trace
+    spans = _profiled(tmp_path, lambda: eng.generate(prompts, 3))
+    (prefill,) = [s for s in spans if s.name == "generate/prefill"]
+    gid = prefill.args["gid"]
+    assert prefill.args["batch"] == 2
+    tokens = sorted((s for s in spans if s.name == "decode/token"),
+                    key=lambda s: s.start)
+    assert [s.args["t"] for s in tokens] == [0, 1, 2]
+    assert prefill.end <= tokens[0].start
+    for tok in tokens:
+        assert tok.args["gid"] == gid and tok.args["batch"] == 2
+        kids = [s for s in spans if s.name.startswith("decode/")
+                and s is not tok and s.args["t"] == tok.args["t"]]
+        assert sorted(s.name for s in kids) == [
+            "decode/dispatch", "decode/sample", "decode/sync"]
+        assert all(_inside(s, tok) and s.args["gid"] == gid for s in kids)

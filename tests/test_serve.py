@@ -30,6 +30,22 @@ def test_generate_shapes_and_determinism(engine):
     np.testing.assert_array_equal(a, b)     # greedy is deterministic
 
 
+def test_engine_programs_have_stable_names(engine):
+    """The jitted steps lower to modules named after them, which is how
+    the profiler trace names their device executions."""
+    cfg, params = engine.cfg, engine.params
+    tokens = jax.ShapeDtypeStruct((2, 4), jax.numpy.int32)
+    prefill = engine._prefill.lower(params=params, tokens=tokens,
+                                    max_len=64)
+    assert prefill.as_text().startswith("module @jit_prefill ")
+    _, cache = jax.eval_shape(
+        lambda p, t: M.prefill(p, cfg, t, max_len=64), params, tokens)
+    decode = engine._decode.lower(
+        params=params, cache=cache,
+        tokens=jax.ShapeDtypeStruct((2,), jax.numpy.int32))
+    assert decode.as_text().startswith("module @jit_decode_step ")
+
+
 def test_generate_matches_stepwise_decode(engine):
     """The engine's batched loop equals manual prefill + decode steps."""
     cfg, params = engine.cfg, engine.params
