@@ -258,80 +258,118 @@ def _windowed(q, k, v, window, chunk, scale, unroll=False, **kw):
 # ---------------------------------------------------------------------------
 # decode: one new token against a KV cache
 # ---------------------------------------------------------------------------
+def _grouped_scores(q, k, scale):
+    """Scores of one query token q (B,1,H,D) against k (B,Sk,Hkv,D) at its
+    stored heads: the H query heads are read as (Hkv, G) groups,
+    G = H // Hkv, so no KV head is repeated (G = 1 is plain multi-head
+    attention).  -> (B,Hkv,G,Sk) float32."""
+    b, _, h, d = q.shape
+    hkv = k.shape[2]
+    qg = q.reshape(b, hkv, h // hkv, d)
+    return jnp.einsum("bhgd,bkhd->bhgk", qg, k,
+                      preferred_element_type=jnp.float32) * scale
+
+
+def _grouped_values(p, v):
+    """Weights p (B,Hkv,G,Sk) against v (B,Sk,Hkv,D) -> (B,1,H,D) f32."""
+    b, hkv, g, _ = p.shape
+    acc = jnp.einsum("bhgk,bkhd->bhgd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return acc.reshape(b, 1, hkv * g, v.shape[-1])
+
+
+def _key_mask(valid, b: int):
+    """(Sk,) or (B,Sk) -> (B,1,1,Sk), to mask grouped scores."""
+    return jnp.broadcast_to(valid, (b, valid.shape[-1]))[:, None, None, :]
+
+
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     length, *, scale: float | None = None) -> jax.Array:
+                     length, *, scale: float | None = None,
+                     new_kv=None) -> jax.Array:
     """q (B,1,H,D); caches (B,Sc,Hkv,D); ``length`` (B,) or scalar = number
     of valid cache entries.  For ring-buffer (windowed) caches the caller
-    passes length = cache size once full."""
-    b, _, h, d = q.shape
-    sc = k_cache.shape[1]
-    hkv = k_cache.shape[2]
-    group = h // hkv
+    passes length = cache size once full.
+
+    ``new_kv`` = (k, v, slot): the token being decoded, k/v (B,1,Hkv,D),
+    which the caller writes to cache slot ``slot`` after this read.  The
+    slot's old entry is left out and the token joins the softmax as one
+    more key, so reading the cache does not wait on the write."""
+    b, _, _, d = q.shape
     if scale is None:
         scale = d ** -0.5
-    kk = jnp.repeat(k_cache, group, axis=2) if group > 1 else k_cache
-    vv = jnp.repeat(v_cache, group, axis=2) if group > 1 else v_cache
-    sct = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
-                     preferred_element_type=jnp.float32) * scale
-    pos = jnp.arange(sc)[None, None, None, :]
-    length = jnp.asarray(length)
-    valid = pos < length.reshape(-1, 1, 1, 1)
-    sct = jnp.where(valid, sct, NEG_INF)
-    p = jax.nn.softmax(sct.astype(jnp.float32), axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv,
-                     preferred_element_type=jnp.float32)
+    pos = jnp.arange(k_cache.shape[1])[None, :]
+    valid = pos < jnp.asarray(length).reshape(-1, 1)
+    if new_kv is not None:
+        valid &= pos != new_kv[2]
+    s = jnp.where(_key_mask(valid, b), _grouped_scores(q, k_cache, scale),
+                  NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if new_kv is not None:
+        s_new = _grouped_scores(q, new_kv[0], scale)
+        m = jnp.maximum(m, s_new)
+    e = jnp.exp(s - m)
+    l = jnp.sum(e, axis=-1, keepdims=True)
+    if new_kv is not None:
+        e_new = jnp.exp(s_new - m)
+        l = l + e_new
+    out = _grouped_values(e / l, v_cache)
+    if new_kv is not None:
+        out = out + _grouped_values(e_new / l, new_kv[1])
     return out.astype(q.dtype)
+
+
+def _decode_piece(q, k, v, valid, scale):
+    """Online-softmax piece of one query token against k/v (B,Sk,Hkv,D);
+    ``valid`` (Sk,) or (B,Sk) masks the keys.  A fully-masked piece has
+    m = NEG_INF and l = 0, so :func:`_merge` ignores it."""
+    b, _, h, _ = q.shape
+    mask = _key_mask(valid, b)
+    s = jnp.where(mask, _grouped_scores(q, k, scale), NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    return (_grouped_values(p, v), m.reshape(b, 1, h, 1),
+            l.reshape(b, 1, h, 1))
 
 
 def decode_attention_blocks(q: jax.Array, read_chunk, n_chunks: int,
                             chunk: int, length, *,
                             scale: float | None = None,
-                            unroll: bool = False) -> jax.Array:
+                            unroll: bool = False, new_kv=None) -> jax.Array:
     """Sequence-blocked decode attention (paged-attention-lite).
 
     ``read_chunk(i)`` returns the (k, v) block (B, C, Hkv, D) for chunk i
     — dequantisation happens per block, so the live working set is one
     block instead of the whole (possibly int8-packed) cache (the temp
     that blows HBM for 32k x batch-128 decode cells).  Pieces merge by
-    online softmax; fully-masked chunks contribute l = 0.
+    online softmax; fully-masked chunks contribute l = 0.  ``new_kv`` as
+    in :func:`decode_attention`, merged as a piece of its own.
     """
-    b, _, h, d = q.shape
+    d = q.shape[-1]
     if scale is None:
         scale = d ** -0.5
 
     def piece_of(i):
         kk, vv = read_chunk(i)
-        kk = kk.astype(q.dtype)
-        vv = vv.astype(q.dtype)
-        sk = kk.shape[1]
-        hkv = kk.shape[2]
-        group = h // hkv
-        if group > 1:
-            kk = jnp.repeat(kk, group, axis=2)
-            vv = jnp.repeat(vv, group, axis=2)
-        sco = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
-                         preferred_element_type=jnp.float32) * scale
-        pos = i * chunk + jnp.arange(sk)
+        pos = i * chunk + jnp.arange(kk.shape[1])
         valid = pos < length
-        sco = jnp.where(valid[None, None, None, :], sco, NEG_INF)
-        m = jnp.max(sco, axis=-1, keepdims=True)
-        p = jnp.where(valid[None, None, None, :], jnp.exp(sco - m), 0.0)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        acc = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv,
-                         preferred_element_type=jnp.float32)
-        m = jnp.transpose(m, (0, 2, 1, 3))
-        l = jnp.transpose(l, (0, 2, 1, 3))
-        # fully-masked chunk: force m to NEG_INF so _merge ignores it
-        m = jnp.where(jnp.any(valid), m, NEG_INF)
-        return acc.astype(jnp.float32), m, l
+        if new_kv is not None:
+            valid &= pos != new_kv[2]
+        return _decode_piece(q, kk.astype(q.dtype), vv.astype(q.dtype),
+                             valid, scale)
 
     if unroll:
         out = piece_of(0)
         for i in range(1, n_chunks):
             out = _merge(out, piece_of(jnp.asarray(i)))
-        return _finalize(out, q.dtype)
-    acc, m, l = jax.lax.map(piece_of, jnp.arange(n_chunks))
-    out = (acc[0], m[0], l[0])
-    for i in range(1, n_chunks):
-        out = _merge(out, (acc[i], m[i], l[i]))
+    else:
+        acc, m, l = jax.lax.map(piece_of, jnp.arange(n_chunks))
+        out = (acc[0], m[0], l[0])
+        for i in range(1, n_chunks):
+            out = _merge(out, (acc[i], m[i], l[i]))
+    if new_kv is not None:
+        k, v, _ = new_kv
+        out = _merge(out, _decode_piece(q, k.astype(q.dtype),
+                                        v.astype(q.dtype),
+                                        jnp.ones((1,), bool), scale))
     return _finalize(out, q.dtype)

@@ -379,10 +379,14 @@ def _cache_len(cfg: ModelConfig, mixer: str, max_len: int) -> int:
     return max_len
 
 
+def _kv_dtype(cfg: ModelConfig):
+    return {"bfloat16": jnp.bfloat16, "int8": jnp.int8,
+            "float32": jnp.float32}[cfg.kv_cache_dtype]
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     """Stacked caches mirroring the scanned/tail param structure."""
-    cache_dtype = {"bfloat16": jnp.bfloat16, "int8": jnp.int8,
-                   "float32": jnp.float32}[cfg.kv_cache_dtype]
+    cache_dtype = _kv_dtype(cfg)
 
     def one(mixer: str) -> dict:
         if mixer in ("attn", "swa"):
@@ -416,9 +420,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     }
 
 
-def _store_kv(cfg: ModelConfig, cache_layer: dict, k, v, idx):
-    """Write k/v (B, S, Hkv, hd) at positions ``idx`` (S,), quantizing for
-    int8 caches."""
+def _quant_kv(cfg: ModelConfig, k, v) -> dict:
+    """k/v (B, S, Hkv, hd) as cache entries: int8 caches store per-vector
+    scales beside the rounded values, the others the values cast."""
     if cfg.kv_cache_dtype == "int8":
         def quant(x):
             amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,
@@ -429,16 +433,15 @@ def _store_kv(cfg: ModelConfig, cache_layer: dict, k, v, idx):
             return q, scale
         kq, ks = quant(k)
         vq, vs = quant(v)
-        return {
-            "k": cache_layer["k"].at[:, idx].set(kq),
-            "v": cache_layer["v"].at[:, idx].set(vq),
-            "k_scale": cache_layer["k_scale"].at[:, idx].set(ks),
-            "v_scale": cache_layer["v_scale"].at[:, idx].set(vs),
-        }
-    return {
-        "k": cache_layer["k"].at[:, idx].set(k.astype(cache_layer["k"].dtype)),
-        "v": cache_layer["v"].at[:, idx].set(v.astype(cache_layer["v"].dtype)),
-    }
+        return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    return {"k": k.astype(_kv_dtype(cfg)), "v": v.astype(_kv_dtype(cfg))}
+
+
+def _store_kv(cfg: ModelConfig, cache_layer: dict, k, v, idx):
+    """Write k/v (B, S, Hkv, hd) at positions ``idx`` (S,), quantizing for
+    int8 caches."""
+    return {name: cache_layer[name].at[:, idx].set(entry)
+            for name, entry in _quant_kv(cfg, k, v).items()}
 
 
 def _read_kv(cfg: ModelConfig, cache_layer: dict):
@@ -451,9 +454,15 @@ def _read_kv(cfg: ModelConfig, cache_layer: dict):
 
 # ---------------------------------------------------------------------------
 # decode
+#
+# The stacked cache is the layer scan's carry and is updated in place: each
+# layer reads its own K/V by dynamic index, then writes its one new position
+# with a dynamic-update-slice at [layer, :, pos % sc].  Jitted with the
+# cache donated (``serve.Engine``), the step reads each cache byte once and
+# makes no cache-sized temporary.
 # ---------------------------------------------------------------------------
-def _attn_decode(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
-                 cache_layer: dict, pos: jax.Array):
+def _attn_decode(layer: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
+                 at, pos: jax.Array):
     compute_dtype = _cd(cfg)
     b = x.shape[0]
     p = layer["attn"]
@@ -475,54 +484,68 @@ def _attn_decode(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
     cos, sin = rope_angles(pos[None, None], cfg.head_dim, cfg.rope_theta)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-    sc = cache_layer["k"].shape[1]
-    idx = (pos % sc)[None]
-    new_cache = {**cache_layer, **_store_kv(cfg, cache_layer, k, v, idx)}
+    sc = cache["k"].shape[2]
+    slot = pos % sc
+    new = _quant_kv(cfg, k, v)
+
+    def read(start, size: int):
+        lay = {name: jax.lax.dynamic_slice(
+                   a, (at, 0, start, 0, 0), (1, b, size) + a.shape[3:])[0]
+               for name, a in cache.items()}
+        return _read_kv(cfg, lay)
+
+    # the layer's K/V are read as they stood, with the new token attended
+    # beside them, so the read does not wait on the write below: XLA then
+    # keeps the cache in its own layout and updates it in place
+    new_kv = (*_read_kv(cfg, new), slot)
     length = jnp.minimum(pos + 1, sc)
     if cfg.decode_chunk and sc > cfg.decode_chunk:
         chunk = cfg.decode_chunk
-        n_chunks = sc // chunk
-
-        def read_chunk(i):
-            lay = {kk: jax.lax.dynamic_slice_in_dim(
-                new_cache[kk], i * chunk, chunk, axis=1)
-                for kk in new_cache}
-            return _read_kv(cfg, lay)
-
         o = attn_mod.decode_attention_blocks(
-            q.astype(compute_dtype), read_chunk, n_chunks, chunk, length,
-            unroll=cfg.unroll_layers)
+            q.astype(compute_dtype), lambda i: read(i * chunk, chunk),
+            sc // chunk, chunk, length, unroll=cfg.unroll_layers,
+            new_kv=new_kv)
     else:
-        kk, vv = _read_kv(cfg, new_cache)
-        o = attn_mod.decode_attention(q, kk, vv, length)
+        o = attn_mod.decode_attention(q, *read(0, sc), length,
+                                      new_kv=new_kv)
+    cache = {name: jax.lax.dynamic_update_slice(
+                 a, new[name][None], (at, 0, slot, 0, 0))
+             for name, a in cache.items()}
     o = o.reshape(b, 1, hq * hd) @ p["wo"].astype(compute_dtype)
-    return x + o.astype(x.dtype), new_cache
+    return x + o.astype(x.dtype), cache
 
 
 def _layer_decode(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
-                  cache_layer: dict, pos: jax.Array):
+                  cache: dict, at, pos: jax.Array):
+    """One layer's step.  ``cache`` is its pattern position's stacked cache
+    (leading layer axis) and ``at`` the layer's index there; returns the
+    stacked cache with that layer updated."""
     if mixer in ("attn", "swa"):
-        x, new_cache = _attn_decode(layer, cfg, mixer, x, cache_layer, pos)
-    elif mixer == "rglru":
-        h = rms_norm(x, layer["norm1"])
-        out, new_cache = rg_mod.rglru_block_decode(
-            layer["rec"], h, cache_layer, _cd(cfg))
-        x = x + out
+        x, cache = _attn_decode(layer, cfg, x, cache, at, pos)
+        return _ffn_apply(layer, cfg, x), cache
+    state = jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, at, keepdims=False), cache)
+    h = rms_norm(x, layer["norm1"])
+    if mixer == "rglru":
+        out, state = rg_mod.rglru_block_decode(layer["rec"], h, state,
+                                               _cd(cfg))
     elif mixer == "rwkv6":
-        h = rms_norm(x, layer["norm1"])
-        out, new_cache = rwkv_mod.time_mix_decode(
-            layer["rwkv"], h, cache_layer, cfg.n_heads, _cd(cfg))
-        x = x + out
+        out, state = rwkv_mod.time_mix_decode(layer["rwkv"], h, state,
+                                              cfg.n_heads, _cd(cfg))
     else:
         raise ValueError(mixer)
+    x = x + out
     if cfg.ffn == "rwkv_cm":
         h = rms_norm(x, layer["norm2"])
-        out, new_cache = rwkv_mod.channel_mix_decode(
-            layer["rwkv"], h, new_cache, _cd(cfg))
+        out, state = rwkv_mod.channel_mix_decode(layer["rwkv"], h, state,
+                                                 _cd(cfg))
         x = x + out
     else:
         x = _ffn_apply(layer, cfg, x)
-    return x, new_cache
+    cache = jax.tree.map(
+        lambda a, s: jax.lax.dynamic_update_index_in_dim(
+            a, s.astype(a.dtype), at, 0), cache, state)
+    return x, cache
 
 
 def decode_step(params: dict, cfg: ModelConfig, cache: dict,
@@ -536,41 +559,34 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict,
         x = tokens[:, None].astype(_cd(cfg))
 
     def group_body(carry, scanned):
-        h = carry
-        group_params, group_cache = scanned
-        new_caches = []
+        h, caches = carry
+        g, group_params = scanned
+        caches = list(caches)
         for p, mixer in enumerate(cfg.pattern):
-            h, nc = _layer_decode(group_params[p], cfg, mixer, h,
-                                  group_cache[p], pos)
-            new_caches.append(nc)
-        return h, tuple(new_caches)
+            h, caches[p] = _layer_decode(group_params[p], cfg, mixer, h,
+                                         caches[p], g, pos)
+        return (h, tuple(caches)), None
 
-    new_cache: dict[str, Any] = {"pos": pos + 1}
+    layers = tuple(cache["layers"])
     if cfg.n_groups > 0:
         if cfg.unroll_layers:
-            collected = []
             for g in range(cfg.n_groups):
-                x, ncg = group_body(
-                    x, (_group_slice(tuple(params["layers"]), g),
-                        _group_slice(tuple(cache["layers"]), g)))
-                collected.append(ncg)
-            new_cache["layers"] = list(_stack_groups(collected))
+                (x, layers), _ = group_body(
+                    (x, layers), (g, _group_slice(tuple(params["layers"]),
+                                                  g)))
         else:
-            x, ncl = jax.lax.scan(group_body, x,
-                                  (tuple(params["layers"]),
-                                   tuple(cache["layers"])))
-            new_cache["layers"] = list(ncl)
-    else:
-        new_cache["layers"] = cache["layers"]
+            (x, layers), _ = jax.lax.scan(
+                group_body, (x, layers),
+                (jnp.arange(cfg.n_groups), tuple(params["layers"])))
     new_tail = []
     for i, mixer in enumerate(cfg.tail_pattern):
-        x, nc = _layer_decode(params["tail"][i], cfg, mixer, x,
-                              cache["tail"][i], pos)
-        new_tail.append(nc)
-    new_cache["tail"] = new_tail
+        one = jax.tree.map(lambda a: a[None], cache["tail"][i])
+        x, one = _layer_decode(params["tail"][i], cfg, mixer, x, one, 0, pos)
+        new_tail.append(jax.tree.map(lambda a: a[0], one))
     h = rms_norm(x, params["final_norm"])
     logits = logits_fn(params, cfg, h)[:, 0]
-    return logits, new_cache
+    return logits, {"layers": list(layers), "tail": new_tail,
+                    "pos": pos + 1}
 
 
 # ---------------------------------------------------------------------------

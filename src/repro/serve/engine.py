@@ -157,7 +157,9 @@ class Engine:
             return M.decode_step(params, cfg, cache, tokens)
 
         self._prefill = jax.jit(prefill, static_argnames=("max_len",))
-        self._decode = jax.jit(decode_step)
+        # the cache is donated: the step updates it in place, and the
+        # caller holds only the returned cache
+        self._decode = jax.jit(decode_step, donate_argnames=("cache",))
         self._tr = _obs_tracer()
         self._gids = itertools.count()
 

@@ -19,6 +19,9 @@ from repro.configs import get_config, list_archs
 from repro.configs.base import smoke
 from repro.models import attention as attn_mod
 from repro.models import model as M
+from repro.models import rglru_block as rg_mod
+from repro.models import rwkv6_block as rwkv_mod
+from repro.models.common import apply_rope, rms_norm, rope_angles
 from repro.train.optimizer import AdamWConfig, init_opt_state
 from repro.train.train_step import train_step
 
@@ -180,20 +183,166 @@ def test_attention_unroll_is_equivalent():
                                    rtol=1e-6, atol=1e-6)
 
 
-def test_decode_attention_matches_full():
-    b, s, h, hkv, d = 2, 40, 4, 2, 16
+@pytest.mark.parametrize("with_new", [False, True])
+@pytest.mark.parametrize("path", ["plain", "blocks"])
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_decode_attention_matches_full(group, path, with_new):
+    """Grouped decode attention (K/V at their stored heads) equals
+    attention against repeated heads, unblocked and blocked, with and
+    without the token being decoded merged as its own piece."""
+    b, s, hkv, d, sc, chunk = 2, 40, 2, 16, 64, 16
+    h = hkv * group
     q = jax.random.normal(jax.random.PRNGKey(10), (b, 1, h, d))
-    kc = jax.random.normal(jax.random.PRNGKey(11), (b, 64, hkv, d))
-    vc = jax.random.normal(jax.random.PRNGKey(12), (b, 64, hkv, d))
-    out = attn_mod.decode_attention(q, kc, vc, length=s)
-    # oracle: same computation with explicit slicing
-    kk = jnp.repeat(kc[:, :s], 2, axis=2)
-    vv = jnp.repeat(vc[:, :s], 2, axis=2)
+    kc = jax.random.normal(jax.random.PRNGKey(11), (b, sc, hkv, d))
+    vc = jax.random.normal(jax.random.PRNGKey(12), (b, sc, hkv, d))
+    kn = jax.random.normal(jax.random.PRNGKey(13), (b, 1, hkv, d))
+    vn = jax.random.normal(jax.random.PRNGKey(14), (b, 1, hkv, d))
+    slot = 17
+    new_kv = (kn, vn, slot) if with_new else None
+    if path == "plain":
+        out = attn_mod.decode_attention(q, kc, vc, length=s, new_kv=new_kv)
+    else:
+        def read_chunk(i):
+            return (jax.lax.dynamic_slice_in_dim(kc, i * chunk, chunk, 1),
+                    jax.lax.dynamic_slice_in_dim(vc, i * chunk, chunk, 1))
+        out = attn_mod.decode_attention_blocks(q, read_chunk, sc // chunk,
+                                               chunk, s, new_kv=new_kv)
+    # oracle: the token written into its slot, explicit slicing, repeated
+    # heads
+    if with_new:
+        kc = kc.at[:, slot].set(kn[:, 0])
+        vc = vc.at[:, slot].set(vn[:, 0])
+    kk = jnp.repeat(kc[:, :s], group, axis=2)
+    vv = jnp.repeat(vc[:, :s], group, axis=2)
     logit = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * d ** -0.5
     p = jax.nn.softmax(logit, axis=-1)
     ref = jnp.einsum("bhqk,bkhd->bqhd", p, vv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# multi-step decode against the plain algorithm
+# ---------------------------------------------------------------------------
+def _reference_attn_decode(layer, cfg, x, cache_layer, pos):
+    """The plain decode attention layer: write the token into this layer's
+    cache, then attend over the whole cache with repeated KV heads."""
+    cd = M._cd(cfg)
+    b = x.shape[0]
+    p = layer["attn"]
+    hq, hkv, hd = cfg.q_heads, cfg.kv_heads, cfg.head_dim
+    h = rms_norm(x, layer["norm1"]).astype(cd)
+    q, k, v = (h @ p[w].astype(cd) for w in ("wq", "wk", "wv"))
+    if cfg.qkv_bias:
+        q, k, v = (t + p[bias].astype(cd)
+                   for t, bias in zip((q, k, v), ("bq", "bk", "bv")))
+    q = q.reshape(b, 1, hq, hd)
+    k = k.reshape(b, 1, hkv, hd)
+    v = v.reshape(b, 1, hkv, hd)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"])
+        k = rms_norm(k, p["k_norm"])
+    cos, sin = rope_angles(pos[None, None], hd, cfg.rope_theta)
+    q = apply_rope(q, cos, sin)
+    k = apply_rope(k, cos, sin)
+    sc = cache_layer["k"].shape[1]
+    new_cache = {**cache_layer,
+                 **M._store_kv(cfg, cache_layer, k, v, (pos % sc)[None])}
+    kk, vv = M._read_kv(cfg, new_cache)
+    if cfg.decode_chunk and sc > cfg.decode_chunk:
+        # the blocked path computes in q's dtype; its blocks, merged by
+        # online softmax, make the same function as one block
+        kk, vv = kk.astype(q.dtype), vv.astype(q.dtype)
+    kk = jnp.repeat(kk, hq // hkv, axis=2)
+    vv = jnp.repeat(vv, hq // hkv, axis=2)
+    sco = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
+                     preferred_element_type=jnp.float32) * hd ** -0.5
+    valid = jnp.arange(sc) < jnp.minimum(pos + 1, sc)
+    sco = jnp.where(valid[None, None, None], sco, attn_mod.NEG_INF)
+    prob = jax.nn.softmax(sco, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", prob.astype(vv.dtype), vv,
+                   preferred_element_type=jnp.float32).astype(q.dtype)
+    o = o.reshape(b, 1, hq * hd) @ p["wo"].astype(cd)
+    return x + o.astype(x.dtype), new_cache
+
+
+def _reference_layer_decode(layer, cfg, mixer, x, cache_layer, pos):
+    cd = M._cd(cfg)
+    if mixer in ("attn", "swa"):
+        x, nc = _reference_attn_decode(layer, cfg, x, cache_layer, pos)
+    elif mixer == "rglru":
+        out, nc = rg_mod.rglru_block_decode(
+            layer["rec"], rms_norm(x, layer["norm1"]), cache_layer, cd)
+        x = x + out
+    else:
+        out, nc = rwkv_mod.time_mix_decode(
+            layer["rwkv"], rms_norm(x, layer["norm1"]), cache_layer,
+            cfg.n_heads, cd)
+        x = x + out
+    if cfg.ffn == "rwkv_cm":
+        out, nc = rwkv_mod.channel_mix_decode(
+            layer["rwkv"], rms_norm(x, layer["norm2"]), nc, cd)
+        return x + out, nc
+    return M._ffn_apply(layer, cfg, x), nc
+
+
+def _reference_decode_step(params, cfg, cache, tokens):
+    """The plain decode step: the stacked cache goes through the layer scan
+    as xs and comes back as new stacked ys."""
+    pos = cache["pos"]
+    x = M.embed_tokens(params, cfg, tokens[:, None])
+
+    def group_body(h, scanned):
+        group_params, group_cache = scanned
+        new = []
+        for p, mixer in enumerate(cfg.pattern):
+            h, nc = _reference_layer_decode(group_params[p], cfg, mixer, h,
+                                            group_cache[p], pos)
+            new.append(nc)
+        return h, tuple(new)
+
+    layers = cache["layers"]
+    if cfg.n_groups > 0:
+        x, ys = jax.lax.scan(group_body, x, (tuple(params["layers"]),
+                                             tuple(layers)))
+        layers = list(ys)
+    tail = []
+    for i, mixer in enumerate(cfg.tail_pattern):
+        x, nc = _reference_layer_decode(params["tail"][i], cfg, mixer, x,
+                                        cache["tail"][i], pos)
+        tail.append(nc)
+    h = rms_norm(x, params["final_norm"])
+    logits = M.logits_fn(params, cfg, h)[:, 0]
+    return logits, {"layers": layers, "tail": tail, "pos": pos + 1}
+
+
+@pytest.mark.parametrize("decode_chunk", [None, 8])
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "float32", "int8"])
+@pytest.mark.parametrize("arch", ARCHS)
+def test_decode_steps_match_plain_algorithm(arch, kv_dtype, decode_chunk):
+    """Prefill, then decode past the sliding-window ring's wrap: the step
+    that updates the cache in place and reads grouped KV heads gives the
+    logits of the plain algorithm at every step."""
+    cfg = dataclasses.replace(smoke(get_config(arch)),
+                              compute_dtype="float32",
+                              kv_cache_dtype=kv_dtype,
+                              decode_chunk=decode_chunk,
+                              capacity_factor=float("inf"))
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    b, s, steps = 2, 12, 8             # positions 12..19: the ring of 16
+    toks, _ = _inputs(cfg, b=b, s=s + steps, seed=5)   # wraps at 16
+    _, cache = M.prefill(params, cfg, toks[:, :s], max_len=24)
+    step = jax.jit(lambda c, t: M.decode_step(params, cfg, c, t))
+    ref_step = jax.jit(lambda c, t: _reference_decode_step(params, cfg, c,
+                                                           t))
+    ref_cache = cache
+    for t in range(s, s + steps):
+        logits, cache = step(cache, toks[:, t])
+        ref_logits, ref_cache = ref_step(ref_cache, toks[:, t])
+        np.testing.assert_allclose(np.asarray(logits),
+                                   np.asarray(ref_logits),
+                                   rtol=2e-3, atol=2e-3)
+    assert int(cache["pos"]) == s + steps
 
 
 # ---------------------------------------------------------------------------

@@ -46,6 +46,37 @@ def test_engine_programs_have_stable_names(engine):
     assert decode.as_text().startswith("module @jit_decode_step ")
 
 
+def test_decode_step_updates_cache_in_place(engine):
+    """The step program donates every cache leaf (each aliases an output),
+    repeats no KV head and builds no zero-filled stacked cache: nothing
+    cache-sized is broadcast.  Two generates in a row on the donated
+    cache give the same tokens."""
+    import re
+    cfg, params = engine.cfg, engine.params
+    tokens = jax.ShapeDtypeStruct((2, 4), jax.numpy.int32)
+    _, cache = jax.eval_shape(
+        lambda p, t: M.prefill(p, cfg, t, max_len=64), params, tokens)
+    text = engine._decode.lower(
+        params=params, cache=cache,
+        tokens=jax.ShapeDtypeStruct((2,), jax.numpy.int32)).as_text()
+    main = next(line for line in text.splitlines()
+                if "func.func public @main" in line)
+    args = re.findall(r"%arg\d+: tensor<([\dx]*)\w+>( \{[^}]*\})?",
+                      main.split(") -> ")[0])
+    assert len(args) == len(jax.tree.leaves((params, cache))) + 1
+    donated = sorted(shape for shape, attrs in args
+                     if "tf.aliasing_output" in attrs)
+    assert donated == sorted("".join(f"{n}x" for n in leaf.shape)
+                             for leaf in jax.tree.leaves(cache))
+    layer = np.prod(cache["layers"][0]["k"].shape[1:])  # one layer's K
+    for shape in re.findall(
+            r"stablehlo.broadcast_in_dim .*-> tensor<([\dx]+)x\w+>", text):
+        assert np.prod([int(n) for n in shape.split("x")]) < layer, shape
+    prompts = np.array([[2, 7, 1, 8], [2, 8, 1, 8]], np.int32)
+    np.testing.assert_array_equal(engine.generate(prompts, 12),
+                                  engine.generate(prompts, 12))
+
+
 def test_generate_matches_stepwise_decode(engine):
     """The engine's batched loop equals manual prefill + decode steps."""
     cfg, params = engine.cfg, engine.params
