@@ -23,9 +23,10 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _flat(tree) -> list[tuple[str, np.ndarray]]:
+def _names(tree) -> list[str]:
+    """One file name per leaf, from its path in the tree."""
     out = []
-    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+    for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]:
         parts = []
         for k in path:
             if hasattr(k, "key"):
@@ -34,8 +35,13 @@ def _flat(tree) -> list[tuple[str, np.ndarray]]:
                 parts.append(str(k.idx))
             else:
                 parts.append(str(k))
-        out.append(("__".join(parts), np.asarray(leaf)))
+        out.append("__".join(parts))
     return out
+
+
+def _flat(tree) -> list[tuple[str, np.ndarray]]:
+    return list(zip(_names(tree),
+                    (np.asarray(x) for x in jax.tree_util.tree_leaves(tree))))
 
 
 _BIT_DTYPES = {"bfloat16": np.uint16, "float8_e4m3fn": np.uint8,
@@ -94,15 +100,16 @@ def latest_step(directory: str) -> int | None:
 
 
 def restore(directory: str, step: int, like, shardings=None):
-    """Load into the structure of ``like``; apply ``shardings`` if given
-    (pytree of NamedSharding matching ``like``) — elastic resharding."""
+    """Load into the structure of ``like`` (arrays or
+    ``jax.ShapeDtypeStruct``s); apply ``shardings`` if given (pytree of
+    NamedSharding matching ``like``) — elastic resharding."""
     path = os.path.join(directory, f"step_{step:08d}")
     if not os.path.exists(os.path.join(path, "COMMIT")):
         raise FileNotFoundError(f"no committed checkpoint at {path}")
     with open(os.path.join(path, "manifest.json")) as f:
         manifest = json.load(f)
     dtype_of = {e["name"]: e["dtype"] for e in manifest["leaves"]}
-    names = [n for n, _ in _flat(like)]
+    names = _names(like)
     arrays = [_from_saved(np.load(os.path.join(path, n + ".npy")),
                           dtype_of.get(n, ""))
               for n in names]

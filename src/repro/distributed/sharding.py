@@ -158,6 +158,28 @@ def batch_spec(mesh: Mesh, global_batch: int) -> P:
     return P(None)
 
 
+#: Activation layouts: the batch over the data axes, heads over ``model``.
+#: Under FSDP x TP each device then holds its own rows of every activation
+#: and gathers the weights it contracts with.
+RESIDUAL = ("batch", None, None)              # (B, S, D)
+HEADS = ("batch", None, "model", None)        # (B, S, H, hd)
+
+
+def constrain(x: jax.Array, layout: tuple) -> jax.Array:
+    """``x`` laid out as ``layout`` when traced under a mesh of ``Auto``
+    axes (``jax.sharding.use_abstract_mesh``); unchanged outside one, so
+    single-device programs compile as if it were not there (and under
+    ``Explicit`` axes, where shardings are types, not hints).  An axis
+    that does not divide its dimension is dropped (replicated)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.are_all_axes_auto:
+        return x
+    first = tuple(batch_spec(mesh, x.shape[0]))[0] \
+        if layout[0] == "batch" else layout[0]
+    spec = _fixup(mesh, (first,) + tuple(layout[1:]), x.shape)
+    return jax.lax.with_sharding_constraint(x, spec)
+
+
 def tokens_sharding(mesh: Mesh, global_batch: int,
                     extra_dims: int = 1) -> NamedSharding:
     spec = batch_spec(mesh, global_batch)

@@ -3,10 +3,10 @@
 Implementations (``AttnImpl``):
 
   ``naive``      full (S,S) masked logits — the oracle; O(S^2) memory.
-  ``chunked``    scan over query chunks against full K/V — O(S*C) memory,
-                 full S^2 FLOPs (masked blocks still computed).  The
-                 paper-faithful tiling baseline: blocking without domain
-                 pruning.
+  ``chunked``    query chunks, each against the keys up to its own end —
+                 ~S^2/2 FLOPs, O(S*C) memory in the forward and, each
+                 chunk recomputed there, in the backward pass (what a
+                 config that trains at long rows chooses: qwen1.5-32b).
   ``recursive``  recursive-halving causal attention: the strictly-causal
                  part decomposes into log2(S/C) levels of *unmasked*
                  rectangular attention (upper-half Q vs lower-half K/V,
@@ -149,7 +149,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             _piece(q, k, v, scale=scale, causal=True, window=window, **kw),
             q.dtype)
     if impl == "chunked":
-        return _chunked(q, k, v, chunk, scale, unroll, **kw)
+        return _chunked(q, k, v, chunk, scale, **kw)
     if impl == "recursive":
         return _recursive(q, k, v, chunk, scale, **kw)
     raise ValueError(f"unknown attention impl {impl!r}")
@@ -164,24 +164,21 @@ def _map(fn, args, unroll: bool):
     return jnp.stack(outs)
 
 
-def _chunked(q, k, v, chunk, scale, unroll=False, **kw):
-    """Scan over q chunks vs full K/V — bounded memory, full FLOPs."""
-    b, s, h, d = q.shape
-    pad = (-s) % chunk
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    n = q.shape[1] // chunk
-    qc = jnp.moveaxis(q.reshape(b, n, chunk, h, d), 1, 0)
+def _chunked(q, k, v, chunk, scale, **kw):
+    """Query chunks, each against the keys up to its own end (a static
+    slice): ~S^2/2 + S*C/2 FLOPs.  Each chunk is recomputed in the
+    backward pass, so neither pass holds more than one chunk's scores."""
+    s = q.shape[1]
+    outs = []
+    for lo in range(0, s, chunk):
+        hi = min(lo + chunk, s)
 
-    def one(args):
-        i, q_i = args
-        return _finalize(
-            _piece(q_i, k, v, scale=scale, row0=i * chunk, causal=True,
-                   **kw),
-            q.dtype)
+        def one(q_i, k_i, v_i, lo=lo):
+            return _finalize(_piece(q_i, k_i, v_i, scale=scale, row0=lo,
+                                    causal=True, **kw), q.dtype)
 
-    out = _map(one, (jnp.arange(n), qc), unroll)
-    return jnp.moveaxis(out, 0, 1).reshape(b, n * chunk, h, d)[:, :s]
+        outs.append(jax.checkpoint(one)(q[:, lo:hi], k[:, :hi], v[:, :hi]))
+    return jnp.concatenate(outs, axis=1)
 
 
 def _recursive(q, k, v, base, scale, **kw):

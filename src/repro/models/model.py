@@ -23,6 +23,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..distributed.sharding import HEADS, RESIDUAL, constrain
 from . import attention as attn_mod
 from . import ffn as ffn_mod
 from . import rglru_block as rg_mod
@@ -211,6 +212,15 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     return params
 
 
+def decay_mask(params: dict) -> dict:
+    """Per leaf, whether weight decay applies: the matrices, not the norm
+    gains and biases.  Leaves of the scanned groups carry a leading layer
+    axis, so their rank within a layer is one less."""
+    def matrix(path, x):
+        return x.ndim - (path[0].key == "layers") >= 2
+    return jax.tree_util.tree_map_with_path(matrix, params)
+
+
 def param_count(params) -> int:
     return sum(int(x.size) for x in jax.tree.leaves(params))
 
@@ -232,9 +242,9 @@ def _attn_apply(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
         q = q + p["bq"].astype(compute_dtype)
         k = k + p["bk"].astype(compute_dtype)
         v = v + p["bv"].astype(compute_dtype)
-    q = q.reshape(b, s, hq, hd)
-    k = k.reshape(b, s, hkv, hd)
-    v = v.reshape(b, s, hkv, hd)
+    q = constrain(q.reshape(b, s, hq, hd), HEADS)
+    k = constrain(k.reshape(b, s, hkv, hd), HEADS)
+    v = constrain(v.reshape(b, s, hkv, hd), HEADS)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"])
         k = rms_norm(k, p["k_norm"])
@@ -244,8 +254,9 @@ def _attn_apply(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
     o = attn_mod.attention(q, k, v, impl=cfg.attn_impl, window=window,
                            chunk=cfg.attn_chunk, unroll=cfg.unroll_layers,
                            score_dtype=_sd(cfg), gqa_grouped=cfg.gqa_grouped)
-    o = o.reshape(b, s, hq * hd) @ p["wo"].astype(compute_dtype)
-    return x + o.astype(x.dtype)
+    o = constrain(o, HEADS).reshape(b, s, hq * hd) \
+        @ p["wo"].astype(compute_dtype)
+    return constrain(x + o.astype(x.dtype), RESIDUAL)
 
 
 def _ffn_apply(layer: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
@@ -298,7 +309,7 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
             positions: jax.Array | None = None) -> jax.Array:
     """tokens (B,S) int32 (or (B,S,D) embeddings for stub-frontend archs)
     -> final hidden states (B,S,D) after the last norm."""
-    x = embed_tokens(params, cfg, tokens)
+    x = constrain(embed_tokens(params, cfg, tokens), RESIDUAL)
     b, s = x.shape[0], x.shape[1]
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.int32)[None]
@@ -307,9 +318,9 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
     def group_body(carry, group_params):
         h = carry
         for p, mixer in enumerate(cfg.pattern):
-            h = _layer_apply(
+            h = constrain(_layer_apply(
                 jax.tree.map(lambda a: a, group_params[p]), cfg, mixer, h,
-                cos, sin)
+                cos, sin), RESIDUAL)
         return h, None
 
     body = group_body
@@ -336,26 +347,26 @@ def logits_fn(params: dict, cfg: ModelConfig,
 def lm_loss(params: dict, cfg: ModelConfig, hidden: jax.Array,
             labels: jax.Array) -> jax.Array:
     """Chunked softmax cross-entropy: logits are never materialised for the
-    whole sequence (vocab 256k x 4k tokens would not fit HBM)."""
+    whole sequence (vocab 256k x 4k tokens would not fit HBM).  A chunk is
+    every row's next ``loss_chunk // B`` positions, so under a mesh each
+    data shard computes the loss of its own rows."""
     b, s, d = hidden.shape
-    t = b * s
-    h = hidden.reshape(t, d)
-    y = labels.reshape(t)
-    chunk = min(cfg.loss_chunk, t)
-    pad = (-t) % chunk
+    chunk = max(1, min(s, cfg.loss_chunk // b))
+    pad = (-s) % chunk
+    h, y = hidden, labels
     if pad:
-        h = jnp.pad(h, ((0, pad), (0, 0)))
-        y = jnp.concatenate([y, -jnp.ones((pad,), y.dtype)])
-    n = h.shape[0] // chunk
-    h = h.reshape(n, chunk, d)
-    y = y.reshape(n, chunk)
+        h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+        y = jnp.pad(y, ((0, 0), (0, pad)), constant_values=-1)
+    n = h.shape[1] // chunk
+    h = jnp.moveaxis(h.reshape(b, n, chunk, d), 1, 0)
+    y = jnp.moveaxis(y.reshape(b, n, chunk), 1, 0)
 
     def chunk_loss(carry, hy):
         h_c, y_c = hy
-        logits = logits_fn(params, cfg, h_c)
+        logits = logits_fn(params, cfg, constrain(h_c, RESIDUAL))
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(
-            logits, jnp.maximum(y_c, 0)[:, None], axis=-1)[:, 0]
+            logits, jnp.maximum(y_c, 0)[..., None], axis=-1)[..., 0]
         valid = (y_c >= 0).astype(jnp.float32)
         nll = (logz - gold) * valid
         return (carry[0] + jnp.sum(nll), carry[1] + jnp.sum(valid)), None

@@ -67,7 +67,11 @@ def global_norm(tree: Any) -> jax.Array:
 
 
 def adamw_update(cfg: AdamWConfig, params: Any, grads: Any,
-                 state: OptState) -> tuple[Any, OptState, dict]:
+                 state: OptState, decay: Any = None
+                 ) -> tuple[Any, OptState, dict]:
+    """One AdamW step.  ``decay`` mirrors ``params`` with a bool per leaf:
+    whether weight decay applies (default: every leaf of rank >= 2; a
+    model that stacks its layers passes ``model.decay_mask``)."""
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-12))
     step = state.step + 1
@@ -75,15 +79,15 @@ def adamw_update(cfg: AdamWConfig, params: Any, grads: Any,
     b1c = 1 - cfg.b1 ** step.astype(jnp.float32)
     b2c = 1 - cfg.b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v, master):
+    def upd(p, g, m, v, master, decays):
         g = g.astype(jnp.float32) * scale
         m = cfg.b1 * m + (1 - cfg.b1) * g
         v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
         mhat = m / b1c
         vhat = v / b2c
         delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
-        # decoupled weight decay (skip 1-d params: norms, biases)
-        wd = cfg.weight_decay if p.ndim >= 2 else 0.0
+        # decoupled weight decay (not on norm gains and biases)
+        wd = cfg.weight_decay if decays else 0.0
         base = p.astype(jnp.float32) if master is None else master
         new_master = base * (1 - lr * wd) - lr * delta
         new_p = new_master.astype(p.dtype)
@@ -95,9 +99,11 @@ def adamw_update(cfg: AdamWConfig, params: Any, grads: Any,
     flat_v = treedef.flatten_up_to(state.v)
     flat_master = treedef.flatten_up_to(state.master) \
         if state.master is not None else [None] * len(flat_p)
-    out = [upd(p, g, m, v, mw)
-           for p, g, m, v, mw in zip(flat_p, flat_g, flat_m, flat_v,
-                                     flat_master)]
+    flat_d = treedef.flatten_up_to(decay) if decay is not None \
+        else [p.ndim >= 2 for p in flat_p]
+    out = [upd(p, g, m, v, mw, d)
+           for p, g, m, v, mw, d in zip(flat_p, flat_g, flat_m, flat_v,
+                                        flat_master, flat_d)]
     new_params = treedef.unflatten([o[0] for o in out])
     new_m = treedef.unflatten([o[1] for o in out])
     new_v = treedef.unflatten([o[2] for o in out])

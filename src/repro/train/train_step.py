@@ -6,7 +6,6 @@ callable the multi-pod dry-run lowers with ShapeDtypeStructs.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -15,7 +14,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..distributed import sharding as sh
 from ..models import model as M
-from .optimizer import AdamWConfig, OptState, adamw_update, init_opt_state
+from .optimizer import AdamWConfig, OptState, adamw_update
 
 
 def loss_fn(params: Any, cfg: M.ModelConfig, tokens: jax.Array,
@@ -56,8 +55,8 @@ def train_step(params: Any, opt_state: OptState, tokens: jax.Array,
         (loss_sum, gsum), _ = jax.lax.scan(one, init, (tb, lb))
         loss = loss_sum / microbatches
         grads = jax.tree.map(lambda g: g / microbatches, gsum)
-    new_params, new_state, metrics = adamw_update(opt_cfg, params, grads,
-                                                  opt_state)
+    new_params, new_state, metrics = adamw_update(
+        opt_cfg, params, grads, opt_state, decay=M.decay_mask(params))
     metrics = {**metrics, "loss": loss}
     return new_params, new_state, metrics
 
@@ -85,8 +84,12 @@ def make_train_step(mesh: Mesh, cfg: M.ModelConfig,
     metric_shard = {k: NamedSharding(mesh, P())
                     for k in ("grad_norm", "lr", "loss")}
 
-    step = functools.partial(train_step, cfg=cfg, opt_cfg=opt_cfg,
-                             microbatches=microbatches)
+    def step(*args):
+        # traced under the mesh: the model's activation constraints apply
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return train_step(*args, cfg=cfg, opt_cfg=opt_cfg,
+                              microbatches=microbatches)
+
     jitted = jax.jit(
         step,
         in_shardings=(p_shard, o_shard, t_shard, l_shard),
@@ -97,7 +100,3 @@ def make_train_step(mesh: Mesh, cfg: M.ModelConfig,
                  "tokens": t_shard, "labels": l_shard}
     return jitted, shardings
 
-
-def init_all(cfg: M.ModelConfig, key: jax.Array):
-    params = M.init_params(cfg, key)
-    return params, init_opt_state(params)

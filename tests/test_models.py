@@ -107,7 +107,7 @@ def test_arch_full_config_exact_dimensions(arch):
         "musicgen-medium": (48, 1536, 24, 24, 6144, 2048),
         "qwen1.5-0.5b": (24, 1024, 16, 16, 2816, 151936),
         "yi-34b": (60, 7168, 56, 8, 20480, 64000),
-        "qwen1.5-32b": (64, 5120, 40, 40, 27392, 152064),
+        "qwen1.5-32b": (64, 5120, 40, 8, 27392, 152064),
         "qwen3-0.6b": (28, 1024, 16, 8, 3072, 151936),
         "rwkv6-1.6b": (24, 2048, 32, 32, 7168, 65536),
         "internvl2-76b": (80, 8192, 64, 8, 28672, 128256),

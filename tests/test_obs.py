@@ -577,3 +577,43 @@ def test_generate_spans_reach_the_profiler_trace(tmp_path):
         assert sorted(s.name for s in kids) == [
             "decode/dispatch", "decode/sample", "decode/sync"]
         assert all(_inside(s, tok) and s.args["gid"] == gid for s in kids)
+
+
+def test_train_spans_reach_the_profiler_trace(tmp_path):
+    """Three steps of ``train``, saved after the second and the last: each
+    step's ``train/data`` wait, then its ``train/step`` with one
+    ``train/sync`` inside, all tagged with the step; the save's stall is
+    ``train/checkpoint``.  The counters count steps and tokens."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import smoke
+    from repro.obs import default_registry
+    from repro.train.loop import TrainConfig, train
+    cfg = dataclasses.replace(smoke(get_config("qwen1.5-32b")), n_layers=2)
+    tc = TrainConfig(total_steps=3, checkpoint_every=2,
+                     checkpoint_dir=str(tmp_path / "ck"), global_batch=4,
+                     seq_len=16, log_every=100)
+    train(cfg, dataclasses.replace(tc, checkpoint_dir=str(tmp_path / "w")))
+    # ... compiled outside the trace
+    reg = default_registry()
+    before = [reg.value(k) for k in ("repro_train_steps_total",
+                                     "repro_train_tokens_total")]
+    spans = _profiled(tmp_path / "trace", lambda: train(cfg, tc))
+    after = [reg.value(k) for k in ("repro_train_steps_total",
+                                    "repro_train_tokens_total")]
+    assert [a - b for a, b in zip(after, before)] == [3, 3 * 4 * 16]
+    steps = sorted((s for s in spans if s.name == "train/step"),
+                   key=lambda s: s.start)
+    assert [s.args["step"] for s in steps] == [0, 1, 2]
+    for st in steps:
+        (sync,) = [s for s in spans if s.name == "train/sync"
+                   and s.args["step"] == st.args["step"]]
+        (data,) = [s for s in spans if s.name == "train/data"
+                   and s.args["step"] == st.args["step"]]
+        assert _inside(sync, st)
+        assert data.row == st.row and data.end <= st.start
+    ckpt = [s for s in spans if s.name == "train/checkpoint"]
+    assert sorted(s.args["step"] for s in ckpt) == [1, 2]
+    for c in ckpt:
+        assert steps[c.args["step"]].end <= c.start

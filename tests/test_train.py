@@ -267,3 +267,81 @@ def test_straggler_transient_blip_not_flagged():
     for step in range(10):
         times = [1.0, 3.0 if step == 5 else 1.0]   # single blip
         assert mon.observe(times) == []
+
+
+def test_decay_mask_spares_stacked_norms_and_biases():
+    """Weight decay goes to the matrices: the scanned layers' norm gains
+    and biases carry a leading layer axis and are still not decayed."""
+    from repro.models import model as M
+    cfg = smoke(get_config("qwen1.5-32b"))
+    shapes = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+    mask = M.decay_mask(shapes)
+    layer = mask["layers"][0]
+    assert shapes["layers"][0]["norm1"].ndim == 2
+    assert not layer["norm1"] and not layer["attn"]["bq"]
+    assert layer["attn"]["wq"] and layer["ffn"]["w2"]
+    assert mask["embed"] and mask["lm_head"] and not mask["final_norm"]
+
+
+# ---------------------------------------------------------------------------
+# the trainer
+# ---------------------------------------------------------------------------
+def test_trainer_step_is_the_train_step():
+    """``Trainer.load`` + ``Trainer.step`` compute what the plain
+    ``train_step`` computes, and count the step and its tokens."""
+    from repro.models import model as M
+    from repro.obs import default_registry
+    from repro.train.loop import Trainer
+    from repro.train.train_step import train_step
+    cfg = dataclasses.replace(smoke(get_config("qwen1.5-32b")), n_layers=2,
+                              compute_dtype="float32")
+    opt_cfg = AdamWConfig(warmup_steps=1, total_steps=10)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    toks = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
+                                         cfg.vocab), np.int32)
+    labels = np.roll(toks, -1, axis=1)
+    p1, o1, m1 = train_step(params, init_opt_state(params), toks, labels,
+                            cfg=cfg, opt_cfg=opt_cfg)
+    reg = default_registry()
+    before = reg.value("repro_train_tokens_total")
+    trainer = Trainer(cfg, global_batch=4, seq_len=16, opt_cfg=opt_cfg)
+    trainer.load(params)
+    m2 = trainer.step(toks, labels)
+    assert m2["loss"] == pytest.approx(float(m1["loss"]), rel=1e-6)
+    assert m2["grad_norm"] == pytest.approx(float(m1["grad_norm"]), rel=1e-5)
+    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(trainer.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    assert reg.value("repro_train_tokens_total") - before == 64
+
+
+def test_trainer_state_is_sharded_from_the_first_byte():
+    """On a 2x2 mesh of four fake devices, the state the trainer makes
+    holds at most a quarter of any 2-D weight (and of its Adam moments)
+    on each device; a restore template allocates nothing."""
+    from conftest import run_subprocess
+    r = run_subprocess("""
+import jax, numpy as np
+from repro.configs import get_config
+from repro.configs.base import smoke
+from repro.launch.mesh import make_mesh
+from repro.train.loop import Trainer
+cfg = smoke(get_config('qwen1.5-32b'))
+mesh = make_mesh((2, 2), ('data', 'model'))
+t = Trainer(cfg, global_batch=4, seq_len=16, mesh=mesh)
+t.init(jax.random.PRNGKey(0))
+trees = [t.params, t.opt_state.m, t.opt_state.v]
+n = 0
+for tree in trees:
+    for x in jax.tree.leaves(tree):
+        if x.ndim - (x.shape[0] == cfg.n_layers) < 2:
+            continue
+        for shard in x.addressable_shards:
+            assert 4 * shard.data.size <= x.size, (x.shape, x.sharding)
+        n += 1
+shapes, shardings = t.template()
+assert all(isinstance(x, jax.ShapeDtypeStruct)
+           for x in jax.tree.leaves(shapes))
+print('OK', n)
+""", n_devices=4, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert r.stdout.split()[0] == "OK" and int(r.stdout.split()[1]) >= 15
