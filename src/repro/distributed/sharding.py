@@ -187,32 +187,34 @@ def tokens_sharding(mesh: Mesh, global_batch: int,
 
 
 def cache_spec(mesh: Mesh, path: str, shape: tuple[int, ...],
-               global_batch: int) -> P:
+               global_batch: int, kv_heads: int) -> P:
     """KV / recurrent cache sharding: batch over DP axes, kv-head (or
-    state-feature) dim over model when divisible."""
+    state-feature) dim over model when divisible.  K/V entries are
+    (B, S, kv_heads * hd) and their int8 scales (B, S, kv_heads): the last
+    dim is head-major, so splitting it over ``model`` when ``kv_heads``
+    divides gives each shard whole heads."""
     scanned = bool(re.search(r"\blayers\b", path))
     base_shape = shape[1:] if scanned else shape
     bspec = batch_spec(mesh, global_batch)
     b_axes = tuple(bspec)[0] if len(tuple(bspec)) else None
     name = path.split("/")[-1]
     fixed: list = [b_axes]
-    if name in ("k", "v", "k_scale", "v_scale") and len(base_shape) == 4:
-        # (B, S, Hkv, hd): shard heads over model when divisible; else
+    if name in ("k", "v", "k_scale", "v_scale") and len(base_shape) == 3:
+        # (B, S, Hkv[*hd]): shard heads over model when divisible; else
         # shard the SEQUENCE dim (sequence-parallel cache: each model
         # shard owns a slice of positions; attention over the cache
         # becomes partial online-softmax pieces XLA merges with two tiny
         # all-reduces).  Without this, kv_heads % model != 0 archs
         # (yi-34b, internvl2, qwen3-*, musicgen) replicate multi-GB
         # caches per chip and blow HBM.
-        hkv = base_shape[2]
         sc = base_shape[1]
         msize = axis_size(mesh, "model") if "model" in mesh.axis_names else 1
-        if hkv % msize == 0:
-            fixed += [None, "model", None]
+        if kv_heads % msize == 0:
+            fixed += [None, "model"]
         elif sc % msize == 0:
-            fixed += ["model", None, None]
+            fixed += ["model", None]
         else:
-            fixed += [None, None, None]
+            fixed += [None, None]
     else:
         fixed += [None] * (len(base_shape) - 1)
     p = _fixup(mesh, tuple(fixed), base_shape)
@@ -221,13 +223,14 @@ def cache_spec(mesh: Mesh, path: str, shape: tuple[int, ...],
     return p
 
 
-def shard_cache(mesh: Mesh, cache: Any, global_batch: int) -> Any:
+def shard_cache(mesh: Mesh, cache: Any, global_batch: int,
+                kv_heads: int) -> Any:
     def spec_of(path, leaf):
         ps = _path_str(path)
         if ps.endswith("pos"):
             return NamedSharding(mesh, P())
         return NamedSharding(mesh, cache_spec(mesh, ps, leaf.shape,
-                                              global_batch))
+                                              global_batch, kv_heads))
     return jax.tree_util.tree_map_with_path(spec_of, cache)
 
 

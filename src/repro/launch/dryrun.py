@@ -116,7 +116,7 @@ def lower_cell(cfg, shape: str, mesh, attn_impl: str | None = None,
     elif kind == "decode":
         cache = jax.eval_shape(
             functools.partial(M.init_cache, cfg, b, s))
-        c_shard = sh.shard_cache(mesh, cache, b)
+        c_shard = sh.shard_cache(mesh, cache, b, cfg.kv_heads)
         if cfg.embed_input:
             tok = jax.ShapeDtypeStruct((b,), jnp.int32)
             t_sh = NamedSharding(mesh, sh.batch_spec(mesh, b))

@@ -22,8 +22,10 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..distributed.sharding import HEADS, RESIDUAL, constrain
+from ..kernels.decode_attention import ops as decode_ops
 from . import attention as attn_mod
 from . import ffn as ffn_mod
 from . import rglru_block as rg_mod
@@ -74,6 +76,8 @@ class ModelConfig:
     # Sequence-blocked decode attention (paged-attention-lite): the KV
     # cache is read/dequantised one block at a time — the live working
     # set shrinks from the whole cache to one block.  None = unblocked.
+    # Unused where the decode attention kernel serves the cache, which
+    # reads the blocks below the length in blocks of its own.
     decode_chunk: int | None = None
     pad_heads_to: int | None = None      # computation padding for TP
     pad_kv_heads_to: int | None = None
@@ -396,21 +400,23 @@ def _kv_dtype(cfg: ModelConfig):
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
-    """Stacked caches mirroring the scanned/tail param structure."""
+    """Stacked caches mirroring the scanned/tail param structure.  K and V
+    are stored as the projections make them, (B, Sc, Hkv*hd): head h is
+    the columns [h*hd, (h+1)*hd), so a block of positions is a lane-dense
+    tile; int8 caches keep one scale per (position, head), (B, Sc, Hkv)."""
     cache_dtype = _kv_dtype(cfg)
 
     def one(mixer: str) -> dict:
         if mixer in ("attn", "swa"):
             sc = _cache_len(cfg, mixer, max_len)
-            c = {"k": jnp.zeros((batch, sc, cfg.kv_heads, cfg.head_dim),
-                                cache_dtype),
-                 "v": jnp.zeros((batch, sc, cfg.kv_heads, cfg.head_dim),
-                                cache_dtype)}
+            row = (batch, sc, cfg.kv_heads * cfg.head_dim)
+            c = {"k": jnp.zeros(row, cache_dtype),
+                 "v": jnp.zeros(row, cache_dtype)}
             if cfg.kv_cache_dtype == "int8":
-                c["k_scale"] = jnp.zeros(
-                    (batch, sc, cfg.kv_heads, 1), jnp.float32)
-                c["v_scale"] = jnp.zeros(
-                    (batch, sc, cfg.kv_heads, 1), jnp.float32)
+                c["k_scale"] = jnp.zeros((batch, sc, cfg.kv_heads),
+                                         jnp.float32)
+                c["v_scale"] = jnp.zeros((batch, sc, cfg.kv_heads),
+                                         jnp.float32)
             return c
         if mixer == "rglru":
             return rg_mod.init_rglru_state(batch, cfg.rnn_width)
@@ -432,8 +438,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
 
 
 def _quant_kv(cfg: ModelConfig, k, v) -> dict:
-    """k/v (B, S, Hkv, hd) as cache entries: int8 caches store per-vector
-    scales beside the rounded values, the others the values cast."""
+    """k/v (B, S, Hkv, hd) as cache entries (B, S, Hkv*hd): int8 caches
+    store per-vector scales (B, S, Hkv) beside the rounded values, the
+    others the values cast."""
+    def rows(x):
+        return x.reshape(x.shape[0], x.shape[1], -1)
+
     if cfg.kv_cache_dtype == "int8":
         def quant(x):
             amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,
@@ -441,11 +451,12 @@ def _quant_kv(cfg: ModelConfig, k, v) -> dict:
             scale = jnp.maximum(amax, 1e-12) / 127.0
             q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale),
                          -127, 127).astype(jnp.int8)
-            return q, scale
+            return rows(q), scale[..., 0]
         kq, ks = quant(k)
         vq, vs = quant(v)
         return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    return {"k": k.astype(_kv_dtype(cfg)), "v": v.astype(_kv_dtype(cfg))}
+    return {"k": rows(k).astype(_kv_dtype(cfg)),
+            "v": rows(v).astype(_kv_dtype(cfg))}
 
 
 def _store_kv(cfg: ModelConfig, cache_layer: dict, k, v, idx):
@@ -456,11 +467,18 @@ def _store_kv(cfg: ModelConfig, cache_layer: dict, k, v, idx):
 
 
 def _read_kv(cfg: ModelConfig, cache_layer: dict):
+    """One layer's cache entries (B, S, Hkv*hd) as k/v (B, S, Hkv, hd),
+    dequantized for int8 caches."""
+    def heads(x):
+        return x.reshape(x.shape[0], x.shape[1], cfg.kv_heads, cfg.head_dim)
+
     if cfg.kv_cache_dtype == "int8":
-        k = cache_layer["k"].astype(jnp.float32) * cache_layer["k_scale"]
-        v = cache_layer["v"].astype(jnp.float32) * cache_layer["v_scale"]
+        k = heads(cache_layer["k"]).astype(jnp.float32) \
+            * cache_layer["k_scale"][..., None]
+        v = heads(cache_layer["v"]).astype(jnp.float32) \
+            * cache_layer["v_scale"][..., None]
         return k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
-    return cache_layer["k"], cache_layer["v"]
+    return heads(cache_layer["k"]), heads(cache_layer["v"])
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +488,11 @@ def _read_kv(cfg: ModelConfig, cache_layer: dict):
 # layer reads its own K/V by dynamic index, then writes its one new position
 # with a dynamic-update-slice at [layer, :, pos % sc].  Jitted with the
 # cache donated (``serve.Engine``), the step reads each cache byte once and
-# makes no cache-sized temporary.
+# makes no cache-sized temporary.  Where the decode attention kernel serves
+# the cache (``decode_ops.serves``: a Pallas implementation, bf16 or
+# float32 entries, heads a multiple of 128 wide, a length that splits into
+# tiled blocks), it reads only the blocks below the step's length, straight
+# from the stacked cache.
 # ---------------------------------------------------------------------------
 def _attn_decode(layer: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
                  at, pos: jax.Array):
@@ -498,29 +520,33 @@ def _attn_decode(layer: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
     sc = cache["k"].shape[2]
     slot = pos % sc
     new = _quant_kv(cfg, k, v)
+    length = jnp.minimum(pos + 1, sc)
 
     def read(start, size: int):
         lay = {name: jax.lax.dynamic_slice(
-                   a, (at, 0, start, 0, 0), (1, b, size) + a.shape[3:])[0]
+                   a, (at, 0, start, 0), (1, b, size, a.shape[3]))[0]
                for name, a in cache.items()}
         return _read_kv(cfg, lay)
 
     # the layer's K/V are read as they stood, with the new token attended
     # beside them, so the read does not wait on the write below: XLA then
     # keeps the cache in its own layout and updates it in place
-    new_kv = (*_read_kv(cfg, new), slot)
-    length = jnp.minimum(pos + 1, sc)
-    if cfg.decode_chunk and sc > cfg.decode_chunk:
+    block = decode_ops.serves(cache["k"].dtype, hd, hkv, sc)
+    if block:
+        o = decode_ops.decode_attention(
+            q, cache["k"], cache["v"], at, length, slot, new["k"], new["v"],
+            block=block)
+    elif cfg.decode_chunk and sc > cfg.decode_chunk:
         chunk = cfg.decode_chunk
         o = attn_mod.decode_attention_blocks(
             q.astype(compute_dtype), lambda i: read(i * chunk, chunk),
             sc // chunk, chunk, length, unroll=cfg.unroll_layers,
-            new_kv=new_kv)
+            new_kv=(*_read_kv(cfg, new), slot))
     else:
         o = attn_mod.decode_attention(q, *read(0, sc), length,
-                                      new_kv=new_kv)
+                                      new_kv=(*_read_kv(cfg, new), slot))
     cache = {name: jax.lax.dynamic_update_slice(
-                 a, new[name][None], (at, 0, slot, 0, 0))
+                 a, new[name][None], (at, 0, slot, 0))
              for name, a in cache.items()}
     o = o.reshape(b, 1, hq * hd) @ p["wo"].astype(compute_dtype)
     return x + o.astype(x.dtype), cache
@@ -557,6 +583,26 @@ def _layer_decode(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
         lambda a, s: jax.lax.dynamic_update_index_in_dim(
             a, s.astype(a.dtype), at, 0), cache, state)
     return x, cache
+
+
+def decode_kv_blocks(cfg: ModelConfig, max_len: int, start: int,
+                     steps: int) -> tuple[int, int]:
+    """(read, skipped) KV cache blocks over ``steps`` decode steps from
+    position ``start``, summed over the attention layers: what the decode
+    attention kernel reads and skips where it serves the cache, and every
+    block read where it does not.  Counted on the host from shapes, by the
+    decision ``_attn_decode`` takes (``decode_ops.serves``)."""
+    mixers = list(cfg.pattern) * cfg.n_groups + list(cfg.tail_pattern)
+    lengths = np.arange(start + 1, start + steps + 1)
+    read = skipped = 0
+    for mixer in mixers:
+        if mixer in ("attn", "swa"):
+            sc = _cache_len(cfg, mixer, max_len)
+            block = decode_ops.serves(_kv_dtype(cfg), cfg.head_dim,
+                                      cfg.kv_heads, sc)
+            r, s = decode_ops.kv_blocks(sc, lengths, block)
+            read, skipped = read + r, skipped + s
+    return read, skipped
 
 
 def decode_step(params: dict, cfg: ModelConfig, cache: dict,

@@ -56,7 +56,7 @@ from ..ft.serve import (BreakerState, ChaosPlan, CircuitBreaker,
 from ..ft.straggler import StragglerConfig, StragglerMonitor
 from ..models import model as M
 from ..obs import (Counter, DriftConfig, DriftDetector, MetricsRegistry,
-                   configure_logging)
+                   configure_logging, default_registry)
 from ..obs import tracer as _obs_tracer
 from .batching import BATCH_SEP, BatchConfig, Batcher
 
@@ -160,6 +160,11 @@ class Engine:
         # the cache is donated: the step updates it in place, and the
         # caller holds only the returned cache
         self._decode = jax.jit(decode_step, donate_argnames=("cache",))
+        blocks = default_registry().counter(
+            "repro_decode_kv_blocks_total",
+            "KV cache blocks the decode steps read or skip", ("kind",))
+        self._kv_read = blocks.labels("read")
+        self._kv_skipped = blocks.labels("skipped")
         self._tr = _obs_tracer()
         self._gids = itertools.count()
 
@@ -191,6 +196,7 @@ class Engine:
         out = np.zeros((b, max_new_tokens), np.int32)
         seen: list[np.ndarray] = []
         done = np.zeros((b,), bool)
+        steps = 0
         for t in range(max_new_tokens):
             with span("token", "decode", gid=gid, t=t, batch=b):
                 with span("sync", "decode", gid=gid, t=t, batch=b):
@@ -205,9 +211,14 @@ class Engine:
                 with span("dispatch", "decode", gid=gid, t=t, batch=b):
                     logits, cache = self._decode(params=self.params,
                                                  cache=cache, tokens=tok)
+                    steps += 1
                 with span("sample", "decode", gid=gid, t=t, batch=b):
                     key, sub = jax.random.split(key)
                     tok = self._sample(logits, sub)
+        read, skipped = M.decode_kv_blocks(self.cfg, self.sc.max_len, p,
+                                           steps)
+        self._kv_read.inc(read)
+        self._kv_skipped.inc(skipped)
         if return_logits:
             return out, np.stack(seen, axis=1)
         return out

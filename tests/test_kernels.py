@@ -8,6 +8,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import kernel_impl
+from repro.kernels.decode_attention import kernel as dec_kernel
+from repro.kernels.decode_attention import ops as dec_ops
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.matmul import ops as mm_ops, ref as mm_ref
 from repro.kernels.quant import ops as q_ops, ref as q_ref
@@ -114,6 +116,110 @@ def test_flash_attention_bf16():
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=3e-2, atol=3e-2)
+
+
+# ---------------------------------------------------------------------------
+# decode attention over the stacked cache
+# ---------------------------------------------------------------------------
+DEC_C, DEC_SC, DEC_D = 8, 32, 128        # KV block, cache length, head width
+
+
+def _decode_case(hkv, g, dtype, seed=30, b=2, layers=3):
+    """q (B,1,H,D), stacked caches (L,B,Sc,Hkv*D), new k/v (B,1,Hkv*D)."""
+    f = hkv * DEC_D
+    return (_rand(seed, (b, 1, hkv * g, DEC_D), dtype),
+            _rand(seed + 1, (layers, b, DEC_SC, f), dtype),
+            _rand(seed + 2, (layers, b, DEC_SC, f), dtype),
+            _rand(seed + 3, (b, 1, f), dtype),
+            _rand(seed + 4, (b, 1, f), dtype))
+
+
+def _decode_xla(q, kc, vc, at, length, slot, kn, vn):
+    """The model's XLA decode attention on layer ``at``, heads split."""
+    from repro.models import attention as attn_mod
+    def heads(x):
+        return x.reshape(x.shape[0], x.shape[1], -1, DEC_D)
+
+    return attn_mod.decode_attention(
+        q, heads(kc[at]), heads(vc[at]), length,
+        new_kv=(heads(kn), heads(vn), slot))
+
+
+def _poisoned(c, length):
+    """NaN in every cache position at or past ``length``."""
+    pos = jnp.arange(c.shape[2])[None, None, :, None]
+    return jnp.where(pos >= length, jnp.nan, c)
+
+
+@pytest.mark.parametrize("slot_at", ["edge", "inside"])
+@pytest.mark.parametrize("length", [1, DEC_C - 1, DEC_C, DEC_C + 1, DEC_SC])
+@pytest.mark.parametrize("hkv,g", [(2, 1), (2, 2), (2, 5), (1, 8), (1, 4)],
+                         ids=["g1", "g2", "g5", "g8", "mqa"])
+def test_decode_attention_kernel_matches_xla(hkv, g, length, slot_at):
+    """The kernel over the blocks below the length equals the XLA decode
+    attention over the whole layer, for GQA groups and MQA, at lengths
+    around a block edge and the full ring, with the new token's slot at
+    the edge of the valid range or inside it.  Positions at or past the
+    length hold NaN for the kernel: they never reach its output."""
+    q, kc, vc, kn, vn = _decode_case(hkv, g, jnp.float32)
+    at = 1
+    slot = length - 1 if slot_at == "edge" else (length - 1) // 2
+    ref = _decode_xla(q, kc, vc, at, length, slot, kn, vn)
+    out = dec_ops.decode_attention(
+        q, _poisoned(kc, length), _poisoned(vc, length), at, length, slot,
+        kn, vn, block=DEC_C, impl="pallas_interpret")
+    assert bool(jnp.all(jnp.isfinite(out)))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_kernel_never_reads_past_length(dtype):
+    """The poison case: NaN at and past a length that ends inside a block,
+    one sequence per block, so the grid walks two sequence blocks; the
+    output is finite and equals the clean cache's through the XLA path."""
+    q, kc, vc, kn, vn = _decode_case(2, 2, dtype, seed=40)
+    at, length, slot = 2, 2 * DEC_C + 3, 2 * DEC_C + 2
+    ref = _decode_xla(q, kc, vc, at, length, slot, kn, vn)
+    b, _, h, d = q.shape
+    out = dec_kernel.decode_attention(
+        q.reshape(b, 2, 2, d), _poisoned(kc, length), _poisoned(vc, length),
+        kn, vn, jnp.array([at, length, slot], jnp.int32), block=DEC_C,
+        seqs=1, scale=d ** -0.5, interpret=True).reshape(b, 1, h, d)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
+def test_decode_attention_block_counts():
+    """Blocks of 128 where they divide the cache, a divisor that keeps the
+    (8, 128) tiling where not, and no kernel where none does, nor for
+    int8 caches, 64-wide heads, a block whose one sequence outgrows a grid
+    step's budget, or the ``xla`` implementation; the count of blocks read
+    and skipped is closed-form."""
+    bf = jnp.bfloat16
+    assert dec_ops.block_size(768) == 128
+    assert dec_ops.block_size(4224) == 128
+    assert dec_ops.block_size(24) == 8
+    assert dec_ops.block_size(100) is None
+    assert dec_ops.block_size(2051) is None
+    with kernel_impl("pallas"):
+        assert dec_ops.serves(bf, 128, 8, 768) == 128
+        assert dec_ops.serves(jnp.float32, 256, 1, 4096) == 128
+        assert dec_ops.serves(bf, 128, 8, 2051) is None
+        assert dec_ops.serves(jnp.int8, 128, 8, 768) is None
+        assert dec_ops.serves(bf, 64, 8, 768) is None
+        assert dec_ops.serves(jnp.float32, 128, 64, 768) is None
+    with kernel_impl("xla"):
+        assert dec_ops.serves(bf, 128, 8, 768) is None
+    assert dec_ops.seq_block(32, 128, 1024 * 2) == 8
+    assert dec_ops.seq_block(6, 128, 1024 * 2) == 6
+    lengths = np.array([1, 128, 129, 768, 900])
+    read, skipped = dec_ops.kv_blocks(768, lengths, 128)
+    assert (read, skipped) == (1 + 1 + 2 + 6 + 6, 5 + 5 + 4 + 0 + 0)
+    assert dec_ops.kv_blocks(2051, lengths, None) == (5 * 17, 0)
 
 
 # ---------------------------------------------------------------------------

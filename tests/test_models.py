@@ -8,6 +8,7 @@ the parallel path).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
@@ -17,6 +18,8 @@ import pytest
 
 from repro.configs import get_config, list_archs
 from repro.configs.base import smoke
+from repro.kernels import kernel_impl
+from repro.kernels.decode_attention import ops as decode_ops
 from repro.models import attention as attn_mod
 from repro.models import model as M
 from repro.models import rglru_block as rg_mod
@@ -26,6 +29,20 @@ from repro.train.optimizer import AdamWConfig, init_opt_state
 from repro.train.train_step import train_step
 
 ARCHS = list_archs()
+# archs with attention layers: where the decode attention kernel can serve
+# the KV cache (under ``pallas_interpret``, with heads 128 wide)
+ATTN_ARCHS = [a for a in ARCHS
+              if {"attn", "swa"} & set(get_config(a).pattern)]
+KERNEL = "pallas_interpret"
+
+
+def _under(impl, cfg):
+    """The config and the kernel scope of a case: ``impl`` None keeps the
+    default implementation and the smoke widths; ``KERNEL`` widens heads
+    to 128 so the decode attention kernel reads bf16 and float32 caches."""
+    if impl is None:
+        return cfg, contextlib.nullcontext()
+    return dataclasses.replace(cfg, head_dim=128), kernel_impl(impl)
 
 
 def _inputs(cfg, b=2, s=24, seed=0):
@@ -76,21 +93,26 @@ def test_arch_smoke_train_step(arch):
     assert delta > 0
 
 
-@pytest.mark.parametrize("arch", ARCHS)
-def test_arch_prefill_decode_consistency(arch):
-    """prefill(t[:s]) then decode(t[s]) must equal prefill(t[:s+1]) logits."""
+@pytest.mark.parametrize(
+    "arch,impl", [pytest.param(a, None, id=a) for a in ARCHS]
+    + [pytest.param(a, KERNEL, id=f"{a}-{KERNEL}") for a in ATTN_ARCHS])
+def test_arch_prefill_decode_consistency(arch, impl):
+    """prefill(t[:s]) then decode(t[s]) must equal prefill(t[:s+1]) logits,
+    also where the decode attention kernel reads the cache."""
     cfg = smoke(get_config(arch))
     # fp32 end-to-end; capacity=inf so MoE token drops (which legitimately
     # depend on batch composition) don't mask the equivalence being tested
     cfg = dataclasses.replace(cfg, compute_dtype="float32",
                               kv_cache_dtype="float32",
                               capacity_factor=float("inf"))
+    cfg, scope = _under(impl, cfg)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     b, s = 2, 12
     toks, _ = _inputs(cfg, b=b, s=s + 1, seed=7)
     logits_full, _ = M.prefill(params, cfg, toks, max_len=32)
-    logits_pre, cache = M.prefill(params, cfg, toks[:, :s], max_len=32)
-    logits_dec, _ = M.decode_step(params, cfg, cache, toks[:, s])
+    with scope:
+        logits_pre, cache = M.prefill(params, cfg, toks[:, :s], max_len=32)
+        logits_dec, _ = M.decode_step(params, cfg, cache, toks[:, s])
     np.testing.assert_allclose(np.asarray(logits_dec),
                                np.asarray(logits_full),
                                rtol=2e-3, atol=2e-3)
@@ -249,9 +271,11 @@ def _reference_attn_decode(layer, cfg, x, cache_layer, pos):
     new_cache = {**cache_layer,
                  **M._store_kv(cfg, cache_layer, k, v, (pos % sc)[None])}
     kk, vv = M._read_kv(cfg, new_cache)
-    if cfg.decode_chunk and sc > cfg.decode_chunk:
-        # the blocked path computes in q's dtype; its blocks, merged by
-        # online softmax, make the same function as one block
+    kernel = decode_ops.serves(new_cache["k"].dtype, hd, hkv, sc)
+    if kernel or (cfg.decode_chunk and sc > cfg.decode_chunk):
+        # the blocked path computes in q's dtype, the kernel in the dtype
+        # q and the cache promote to; their blocks, merged by online
+        # softmax, make the same function as one block
         kk, vv = kk.astype(q.dtype), vv.astype(q.dtype)
     kk = jnp.repeat(kk, hq // hkv, axis=2)
     vv = jnp.repeat(vv, hq // hkv, axis=2)
@@ -316,18 +340,38 @@ def _reference_decode_step(params, cfg, cache, tokens):
     return logits, {"layers": layers, "tail": tail, "pos": pos + 1}
 
 
-@pytest.mark.parametrize("decode_chunk", [None, 8])
-@pytest.mark.parametrize("kv_dtype", ["bfloat16", "float32", "int8"])
-@pytest.mark.parametrize("arch", ARCHS)
-def test_decode_steps_match_plain_algorithm(arch, kv_dtype, decode_chunk):
+def _decode_step_cases():
+    """arch x KV cache dtype x decode_chunk, as they are, then again under
+    the decode attention kernel for one arch of each attention shape: GQA
+    with q/k norms, a sliding-window ring with MoE, MQA between recurrent
+    layers with a tail layer, and multi-head attention with q/k/v bias."""
+    grid = [(kv, chunk) for kv in ("bfloat16", "float32", "int8")
+            for chunk in (None, 8)]
+    kernel_archs = ("qwen3-0.6b", "mixtral-8x7b", "recurrentgemma-9b",
+                    "qwen1.5-0.5b")
+    return ([pytest.param(a, kv, chunk, None, id=f"{a}-{kv}-{chunk}")
+             for a in ARCHS for kv, chunk in grid]
+            + [pytest.param(a, kv, chunk, KERNEL,
+                            id=f"{a}-{kv}-{chunk}-{KERNEL}")
+               for a in kernel_archs for kv, chunk in grid])
+
+
+@pytest.mark.parametrize("arch,kv_dtype,decode_chunk,impl",
+                         _decode_step_cases())
+def test_decode_steps_match_plain_algorithm(arch, kv_dtype, decode_chunk,
+                                            impl):
     """Prefill, then decode past the sliding-window ring's wrap: the step
     that updates the cache in place and reads grouped KV heads gives the
-    logits of the plain algorithm at every step."""
+    logits of the plain algorithm at every step; under the kernel, bf16
+    and float32 caches are read by the decode attention kernel (three
+    blocks of 8 at max_len 24, whatever ``decode_chunk``), int8 ones by
+    the XLA path."""
     cfg = dataclasses.replace(smoke(get_config(arch)),
                               compute_dtype="float32",
                               kv_cache_dtype=kv_dtype,
                               decode_chunk=decode_chunk,
                               capacity_factor=float("inf"))
+    cfg, scope = _under(impl, cfg)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     b, s, steps = 2, 12, 8             # positions 12..19: the ring of 16
     toks, _ = _inputs(cfg, b=b, s=s + steps, seed=5)   # wraps at 16
@@ -336,12 +380,13 @@ def test_decode_steps_match_plain_algorithm(arch, kv_dtype, decode_chunk):
     ref_step = jax.jit(lambda c, t: _reference_decode_step(params, cfg, c,
                                                            t))
     ref_cache = cache
-    for t in range(s, s + steps):
-        logits, cache = step(cache, toks[:, t])
-        ref_logits, ref_cache = ref_step(ref_cache, toks[:, t])
-        np.testing.assert_allclose(np.asarray(logits),
-                                   np.asarray(ref_logits),
-                                   rtol=2e-3, atol=2e-3)
+    with scope:
+        for t in range(s, s + steps):
+            logits, cache = step(cache, toks[:, t])
+            ref_logits, ref_cache = ref_step(ref_cache, toks[:, t])
+            np.testing.assert_allclose(np.asarray(logits),
+                                       np.asarray(ref_logits),
+                                       rtol=2e-3, atol=2e-3)
     assert int(cache["pos"]) == s + steps
 
 

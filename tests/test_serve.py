@@ -271,3 +271,33 @@ def test_plan_engine_reasserts_its_pool_contract():
     compiled_program(g, plan, "xla", pool_size=1)   # foreign rebuild
     eng.submit("m", ins)
     assert eng.stats()["pools"]["m/xla"]["pool_size"] == 2
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_generate_counts_kv_blocks_read_and_skipped(impl):
+    """``Engine.generate`` adds the closed-form counts of the KV blocks its
+    decode steps read and skip to ``repro_decode_kv_blocks_total``: where
+    the decode attention kernel serves the cache, a step at position p
+    reads the ceil((p + 1) / 128) blocks below its length of a layer's
+    Sc / 128; where it does not, every block is read.  Read plus skipped
+    is steps x attention layers x blocks either way."""
+    from repro.kernels import kernel_impl
+    from repro.obs import default_registry
+    cfg = dataclasses.replace(smoke(get_config("qwen3-0.6b")),
+                              compute_dtype="float32", head_dim=128)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    eng = Engine(cfg, params, ServeConfig(max_len=256))
+    blocks = default_registry().counter("repro_decode_kv_blocks_total",
+                                        labelnames=("kind",))
+    before = [blocks.labels(k).value for k in ("read", "skipped")]
+    prompt, steps, layers, per_layer = 120, 12, cfg.n_layers, 256 // 128
+    prompts = np.arange(2 * prompt, dtype=np.int32).reshape(2, -1) \
+        % cfg.vocab
+    with kernel_impl(impl):
+        eng.generate(prompts, steps)
+    read, skipped = (blocks.labels(k).value - b
+                     for k, b in zip(("read", "skipped"), before))
+    # positions 120..131: lengths 121..128 cover one block, 129..132 two
+    want = layers * (8 + 4 * 2) if impl == "pallas_interpret" \
+        else layers * steps * per_layer
+    assert (read, skipped) == (want, layers * steps * per_layer - want)
