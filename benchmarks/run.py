@@ -1,4 +1,4 @@
-"""Benchmark driver: one table per paper table + the roofline table.
+"""Benchmark driver: one table per paper table.
 
     PYTHONPATH=src python -m benchmarks.run            # all tables
     PYTHONPATH=src python -m benchmarks.run table6     # one table
@@ -16,9 +16,9 @@ def main() -> None:
     args = [a for a in args if not a.startswith("--")]
     budget = 6.0 if fast else 12.0
 
-    from . import (roofline_table, table5_characteristics,
-                   table6_polybench, table7_resources, table8_multislice,
-                   table9_plans, table10_solver_time)
+    from . import (table5_characteristics, table6_polybench,
+                   table7_resources, table8_multislice, table9_plans,
+                   table10_solver_time)
     jobs = {
         "table5": lambda: table5_characteristics.run(),
         "table6": lambda: table6_polybench.run(budget=budget),
@@ -27,8 +27,6 @@ def main() -> None:
         "table9": lambda: table9_plans.run(budget=budget),
         "table10": lambda: table10_solver_time.run(
             budget=10.0 if fast else 20.0),
-        "roofline": lambda: roofline_table.run("single"),
-        "roofline_multi": lambda: roofline_table.run("multi"),
     }
     selected = args or list(jobs)
     t_all = time.monotonic()

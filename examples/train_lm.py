@@ -10,8 +10,8 @@ checkpointing, deterministic data, any assigned --arch at a reduced depth.
     # smoke (CI): PYTHONPATH=src python examples/train_lm.py --steps 8 \
     #     --preset tiny
 
-The same loop, step function and sharding rules the 512-chip dry-run
-lowers — here jitted on the local device mesh.
+The same loop, step function and sharding rules as the
+``qwen1.5-32b.train`` benchmark cell — here jitted on the local device mesh.
 """
 import argparse
 import dataclasses

@@ -41,19 +41,6 @@ def axis_size(mesh: Mesh, axes) -> int:
     return n
 
 
-# Per-process rule overrides (name-pattern -> spec template).  The §Perf
-# loop uses this to test alternative layouts, e.g. lm_head (None, "model")
-# — replicating the contraction dim trades a small params all-gather for
-# NOT partial-sum-all-reducing the (tokens x vocab) f32 logits.
-_OVERRIDES: dict[str, tuple] = {}
-
-
-def set_overrides(overrides: dict[str, tuple | list]) -> None:
-    _OVERRIDES.clear()
-    for k, v in (overrides or {}).items():
-        _OVERRIDES[k] = tuple(None if x is None else x for x in v)
-
-
 # name -> spec template (checked/fixed against shapes at assignment)
 _RULES: list[tuple[str, tuple]] = [
     (r"embed$", ("model", "data")),
@@ -104,13 +91,7 @@ def param_spec(mesh: Mesh, path: str, shape: tuple[int, ...]) -> P:
     base_shape = shape[1:] if scanned else shape
     name = path.split("/")[-1]
     spec: tuple | None = None
-    for pat, sp in _OVERRIDES.items():
-        if re.search(pat, name):
-            spec = sp
-            break
-    if spec is not None:
-        pass
-    elif re.search(r"w[123]$", name) and len(base_shape) == 3:
+    if re.search(r"w[123]$", name) and len(base_shape) == 3:
         div = base_shape[0] % axis_size(mesh, "model") == 0 \
             if "model" in mesh.axis_names else False
         spec = _MOE_RULES[(name, div)]
@@ -184,54 +165,6 @@ def tokens_sharding(mesh: Mesh, global_batch: int,
                     extra_dims: int = 1) -> NamedSharding:
     spec = batch_spec(mesh, global_batch)
     return NamedSharding(mesh, P(*(tuple(spec) + (None,) * extra_dims)))
-
-
-def cache_spec(mesh: Mesh, path: str, shape: tuple[int, ...],
-               global_batch: int, kv_heads: int) -> P:
-    """KV / recurrent cache sharding: batch over DP axes, kv-head (or
-    state-feature) dim over model when divisible.  K/V entries are
-    (B, S, kv_heads * hd) and their int8 scales (B, S, kv_heads): the last
-    dim is head-major, so splitting it over ``model`` when ``kv_heads``
-    divides gives each shard whole heads."""
-    scanned = bool(re.search(r"\blayers\b", path))
-    base_shape = shape[1:] if scanned else shape
-    bspec = batch_spec(mesh, global_batch)
-    b_axes = tuple(bspec)[0] if len(tuple(bspec)) else None
-    name = path.split("/")[-1]
-    fixed: list = [b_axes]
-    if name in ("k", "v", "k_scale", "v_scale") and len(base_shape) == 3:
-        # (B, S, Hkv[*hd]): shard heads over model when divisible; else
-        # shard the SEQUENCE dim (sequence-parallel cache: each model
-        # shard owns a slice of positions; attention over the cache
-        # becomes partial online-softmax pieces XLA merges with two tiny
-        # all-reduces).  Without this, kv_heads % model != 0 archs
-        # (yi-34b, internvl2, qwen3-*, musicgen) replicate multi-GB
-        # caches per chip and blow HBM.
-        sc = base_shape[1]
-        msize = axis_size(mesh, "model") if "model" in mesh.axis_names else 1
-        if kv_heads % msize == 0:
-            fixed += [None, "model"]
-        elif sc % msize == 0:
-            fixed += ["model", None]
-        else:
-            fixed += [None, None]
-    else:
-        fixed += [None] * (len(base_shape) - 1)
-    p = _fixup(mesh, tuple(fixed), base_shape)
-    if scanned:
-        p = P(None, *p)
-    return p
-
-
-def shard_cache(mesh: Mesh, cache: Any, global_batch: int,
-                kv_heads: int) -> Any:
-    def spec_of(path, leaf):
-        ps = _path_str(path)
-        if ps.endswith("pos"):
-            return NamedSharding(mesh, P())
-        return NamedSharding(mesh, cache_spec(mesh, ps, leaf.shape,
-                                              global_batch, kv_heads))
-    return jax.tree_util.tree_map_with_path(spec_of, cache)
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

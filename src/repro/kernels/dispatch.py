@@ -1,9 +1,8 @@
 """Kernel implementation dispatch.
 
 Implementations:
-  ``xla``               pure-jnp reference path (the ref.py oracles) — used by
-                        the 512-device dry-runs (that is what cost_analysis
-                        inspects) and as the numerical oracle.
+  ``xla``               pure-jnp reference path (the ref.py oracles) — the
+                        CPU default and the numerical oracle.
   ``pallas_interpret``  Pallas kernel bodies executed in interpret mode on
                         CPU — how this container validates the TPU kernels.
   ``pallas``            compiled Pallas (Mosaic) — the TPU target.

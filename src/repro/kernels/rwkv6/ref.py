@@ -4,7 +4,8 @@ Sequence-chunked with a rematerialised (checkpointed) inner scan: the
 backward pass stores only chunk-boundary states ((BH, dk, dv) every
 ``chunk`` steps) instead of every per-step state — without this, training
 rwkv6-1.6b at 4k context materialises TBs of per-step (dk, dv) states
-(observed: 1.66 TB/chip temp in the dry-run memory analysis).
+(observed: 1.66 TB/chip of temporaries in the compiled step's memory
+analysis).
 """
 from __future__ import annotations
 

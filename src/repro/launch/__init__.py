@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers."""
+"""Launch helpers: the device mesh the trainer and the benchmark build."""

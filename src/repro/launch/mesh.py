@@ -1,9 +1,9 @@
 """Mesh construction.
 
-FUNCTIONS (not module-level constants) so importing this module never
-touches jax device state — the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first jax
-init; tests and benches see the real devices.
+A function, not a module-level constant, so importing this module never
+touches jax device state: multi-device tests set
+``XLA_FLAGS=--xla_force_host_platform_device_count`` before jax starts, and
+compiles for a described topology pass its devices in.
 
 Every mesh here has ``Auto`` axes: the model code places arrays through
 ``NamedSharding`` constraints and lets the compiler propagate the rest.
@@ -29,13 +29,3 @@ def make_mesh(shape, axes, devices=None):
                          axis_types=(AxisType.Auto,) * len(shape),
                          devices=devices[:n])
 
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if len(jax.devices()) < int(np.prod(shape)):
-        raise RuntimeError(
-            f"need {int(np.prod(shape))} devices for the production mesh, "
-            f"have {len(jax.devices())} — run under dryrun.py which sets "
-            f"--xla_force_host_platform_device_count=512")
-    return make_mesh(shape, axes)

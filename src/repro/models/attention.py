@@ -122,14 +122,12 @@ def _finalize(piece, dtype):
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               impl: AttnImpl = "chunked", window: int | None = None,
               chunk: int = 512, scale: float | None = None,
-              unroll: bool = False, score_dtype=jnp.float32,
+              score_dtype=jnp.float32,
               gqa_grouped: bool = False) -> jax.Array:
     """Causal (optionally sliding-window) self attention.
 
     q (B,S,H,D); k,v (B,S,Hkv,D) with H % Hkv == 0.  Returns (B,S,H,D).
     ``window`` counts the current token (window=1 sees only itself).
-    ``unroll`` python-unrolls the chunk maps (dry-run cost fidelity:
-    HloCostAnalysis counts a loop body once; unrolled bodies count fully).
     """
     b, s, h, d = q.shape
     if scale is None:
@@ -140,8 +138,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kw = dict(score_dtype=score_dtype, gqa_grouped=gqa_grouped)
     if window is not None and impl != "naive":
         if s > window:
-            return _windowed(q, k, v, window, min(chunk, s), scale, unroll,
-                             **kw)
+            return _windowed(q, k, v, window, min(chunk, s), scale, **kw)
         # window covers everything: plain causal
         window = None
     if impl == "naive" or s <= chunk:
@@ -153,15 +150,6 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if impl == "recursive":
         return _recursive(q, k, v, chunk, scale, **kw)
     raise ValueError(f"unknown attention impl {impl!r}")
-
-
-def _map(fn, args, unroll: bool):
-    """lax.map, or a python loop when ``unroll`` (cost-visible HLO)."""
-    if not unroll:
-        return jax.lax.map(fn, args)
-    n = args[0].shape[0]
-    outs = [fn(tuple(a[i] for a in args)) for i in range(n)]
-    return jnp.stack(outs)
 
 
 def _chunked(q, k, v, chunk, scale, **kw):
@@ -222,7 +210,7 @@ def _recursive(q, k, v, base, scale, **kw):
     return out[:, :s]
 
 
-def _windowed(q, k, v, window, chunk, scale, unroll=False, **kw):
+def _windowed(q, k, v, window, chunk, scale, **kw):
     """Sliding window: each q chunk gathers a (window+chunk) KV slice.
 
     KV is left-padded by ``span`` so slices are fixed-size; masking uses
@@ -248,7 +236,7 @@ def _windowed(q, k, v, window, chunk, scale, unroll=False, **kw):
                        causal=True, window=window, **kw)
         return _finalize(piece, q.dtype)
 
-    out = _map(one, (jnp.arange(n), qc), unroll)
+    out = jax.lax.map(one, (jnp.arange(n), qc))
     return jnp.moveaxis(out, 0, 1).reshape(b, n * chunk, h, d)[:, :s]
 
 
@@ -332,7 +320,7 @@ def _decode_piece(q, k, v, valid, scale):
 def decode_attention_blocks(q: jax.Array, read_chunk, n_chunks: int,
                             chunk: int, length, *,
                             scale: float | None = None,
-                            unroll: bool = False, new_kv=None) -> jax.Array:
+                            new_kv=None) -> jax.Array:
     """Sequence-blocked decode attention (paged-attention-lite).
 
     ``read_chunk(i)`` returns the (k, v) block (B, C, Hkv, D) for chunk i
@@ -355,15 +343,10 @@ def decode_attention_blocks(q: jax.Array, read_chunk, n_chunks: int,
         return _decode_piece(q, kk.astype(q.dtype), vv.astype(q.dtype),
                              valid, scale)
 
-    if unroll:
-        out = piece_of(0)
-        for i in range(1, n_chunks):
-            out = _merge(out, piece_of(jnp.asarray(i)))
-    else:
-        acc, m, l = jax.lax.map(piece_of, jnp.arange(n_chunks))
-        out = (acc[0], m[0], l[0])
-        for i in range(1, n_chunks):
-            out = _merge(out, (acc[i], m[i], l[i]))
+    acc, m, l = jax.lax.map(piece_of, jnp.arange(n_chunks))
+    out = (acc[0], m[0], l[0])
+    for i in range(1, n_chunks):
+        out = _merge(out, (acc[i], m[i], l[i]))
     if new_kv is not None:
         k, v, _ = new_kv
         out = _merge(out, _decode_piece(q, k.astype(q.dtype),

@@ -3,8 +3,8 @@
 Layer stacking uses ``lax.scan`` over *groups* (one group = one repetition
 of the mixer ``pattern``, e.g. RecurrentGemma's (rglru, rglru, attn)), with
 the non-dividing remainder unrolled as ``tail`` layers.  Scan keeps the HLO
-O(1) in depth — required for the 512-device dry-run compiles — and is remat
-boundary.
+O(1) in depth, so a step compiles in the same time at any depth, and each
+group is a remat boundary.
 
 Mixers: ``attn`` (full causal), ``swa`` (sliding window), ``rglru``
 (RecurrentGemma recurrent block), ``rwkv6`` (Finch time-mix).
@@ -59,9 +59,6 @@ class ModelConfig:
     capacity_factor: float = 1.25
     d_rnn: int = 0                       # rglru recurrence width
     remat: bool = True
-    # Dry-run fidelity: python-unroll the layer/loss scans so XLA's
-    # HloCostAnalysis (which counts while bodies ONCE) sees every layer.
-    unroll_layers: bool = False
     compute_dtype: str = "bfloat16"      # or "float32" (tests/debug)
     # Parameter storage dtype.  "bfloat16" stores model weights in bf16
     # (casts vanish from the forward pass; gradients and their DP
@@ -113,19 +110,6 @@ def _cd(cfg: "ModelConfig"):
 def _sd(cfg: "ModelConfig"):
     return jnp.bfloat16 if cfg.attn_score_dtype == "bfloat16" \
         else jnp.float32
-
-
-def _group_slice(stacked, g: int):
-    return tuple(jax.tree.map(lambda a: a[g], pos) for pos in stacked)
-
-
-def _stack_groups(per_group: list):
-    # list over groups of tuples over positions -> tuple of stacked trees
-    n_pos = len(per_group[0])
-    return tuple(
-        jax.tree.map(lambda *xs: jnp.stack(xs),
-                     *[per_group[g][p] for g in range(len(per_group))])
-        for p in range(n_pos))
 
 
 # ---------------------------------------------------------------------------
@@ -232,13 +216,16 @@ def param_count(params) -> int:
 # ---------------------------------------------------------------------------
 # forward (training / prefill)
 # ---------------------------------------------------------------------------
-def _attn_apply(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
-                cos, sin) -> jax.Array:
+def _qkv(layer: dict, cfg: ModelConfig, x: jax.Array, cos, sin):
+    """The attention layer's projection of ``x`` (B, S, D), shared by
+    training, prefill and decode: pre-norm, q/k/v in the compute dtype
+    with their optional biases, split into heads (B, S, H, hd), the
+    optional q/k norms, then RoPE at the angles ``cos``/``sin`` of the S
+    positions.  -> (q, k, v)."""
     compute_dtype = _cd(cfg)
-    b, s, d = x.shape
+    b, s, _ = x.shape
     p = layer["attn"]
     h = rms_norm(x, layer["norm1"]).astype(compute_dtype)
-    hq, hkv, hd = cfg.q_heads, cfg.kv_heads, cfg.head_dim
     q = h @ p["wq"].astype(compute_dtype)
     k = h @ p["wk"].astype(compute_dtype)
     v = h @ p["wv"].astype(compute_dtype)
@@ -246,20 +233,25 @@ def _attn_apply(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
         q = q + p["bq"].astype(compute_dtype)
         k = k + p["bk"].astype(compute_dtype)
         v = v + p["bv"].astype(compute_dtype)
-    q = constrain(q.reshape(b, s, hq, hd), HEADS)
-    k = constrain(k.reshape(b, s, hkv, hd), HEADS)
-    v = constrain(v.reshape(b, s, hkv, hd), HEADS)
+    q = constrain(q.reshape(b, s, cfg.q_heads, cfg.head_dim), HEADS)
+    k = constrain(k.reshape(b, s, cfg.kv_heads, cfg.head_dim), HEADS)
+    v = constrain(v.reshape(b, s, cfg.kv_heads, cfg.head_dim), HEADS)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"])
         k = rms_norm(k, p["k_norm"])
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+def _attn_apply(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
+                cos, sin) -> jax.Array:
+    b, s, _ = x.shape
+    q, k, v = _qkv(layer, cfg, x, cos, sin)
     window = cfg.window if mixer == "swa" else None
     o = attn_mod.attention(q, k, v, impl=cfg.attn_impl, window=window,
-                           chunk=cfg.attn_chunk, unroll=cfg.unroll_layers,
-                           score_dtype=_sd(cfg), gqa_grouped=cfg.gqa_grouped)
-    o = constrain(o, HEADS).reshape(b, s, hq * hd) \
-        @ p["wo"].astype(compute_dtype)
+                           chunk=cfg.attn_chunk, score_dtype=_sd(cfg),
+                           gqa_grouped=cfg.gqa_grouped)
+    o = constrain(o, HEADS).reshape(b, s, cfg.q_heads * cfg.head_dim) \
+        @ layer["attn"]["wo"].astype(_cd(cfg))
     return constrain(x + o.astype(x.dtype), RESIDUAL)
 
 
@@ -322,20 +314,15 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
     def group_body(carry, group_params):
         h = carry
         for p, mixer in enumerate(cfg.pattern):
-            h = constrain(_layer_apply(
-                jax.tree.map(lambda a: a, group_params[p]), cfg, mixer, h,
-                cos, sin), RESIDUAL)
+            h = constrain(_layer_apply(group_params[p], cfg, mixer, h,
+                                       cos, sin), RESIDUAL)
         return h, None
 
     body = group_body
     if cfg.remat:
         body = jax.checkpoint(group_body, prevent_cse=False)
     if cfg.n_groups > 0:
-        if cfg.unroll_layers:
-            for g in range(cfg.n_groups):
-                x, _ = body(x, _group_slice(tuple(params["layers"]), g))
-        else:
-            x, _ = jax.lax.scan(body, x, tuple(params["layers"]))
+        x, _ = jax.lax.scan(body, x, tuple(params["layers"]))
     for i, mixer in enumerate(cfg.tail_pattern):
         x = _layer_apply(params["tail"][i], cfg, mixer, x, cos, sin)
     return rms_norm(x, params["final_norm"])
@@ -375,13 +362,7 @@ def lm_loss(params: dict, cfg: ModelConfig, hidden: jax.Array,
         nll = (logz - gold) * valid
         return (carry[0] + jnp.sum(nll), carry[1] + jnp.sum(valid)), None
 
-    if cfg.unroll_layers:
-        carry = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
-        for i in range(n):
-            carry, _ = chunk_loss(carry, (h[i], y[i]))
-        total, count = carry
-    else:
-        (total, count), _ = jax.lax.scan(chunk_loss, (0.0, 0.0), (h, y))
+    (total, count), _ = jax.lax.scan(chunk_loss, (0.0, 0.0), (h, y))
     return total / jnp.maximum(count, 1.0)
 
 
@@ -498,25 +479,9 @@ def _attn_decode(layer: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
                  at, pos: jax.Array):
     compute_dtype = _cd(cfg)
     b = x.shape[0]
-    p = layer["attn"]
-    h = rms_norm(x, layer["norm1"]).astype(compute_dtype)
     hq, hkv, hd = cfg.q_heads, cfg.kv_heads, cfg.head_dim
-    q = h @ p["wq"].astype(compute_dtype)
-    k = h @ p["wk"].astype(compute_dtype)
-    v = h @ p["wv"].astype(compute_dtype)
-    if cfg.qkv_bias:
-        q = q + p["bq"].astype(compute_dtype)
-        k = k + p["bk"].astype(compute_dtype)
-        v = v + p["bv"].astype(compute_dtype)
-    q = q.reshape(b, 1, hq, hd)
-    k = k.reshape(b, 1, hkv, hd)
-    v = v.reshape(b, 1, hkv, hd)
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
-    cos, sin = rope_angles(pos[None, None], cfg.head_dim, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    cos, sin = rope_angles(pos[None, None], hd, cfg.rope_theta)
+    q, k, v = _qkv(layer, cfg, x, cos, sin)
     sc = cache["k"].shape[2]
     slot = pos % sc
     new = _quant_kv(cfg, k, v)
@@ -540,15 +505,14 @@ def _attn_decode(layer: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
         chunk = cfg.decode_chunk
         o = attn_mod.decode_attention_blocks(
             q.astype(compute_dtype), lambda i: read(i * chunk, chunk),
-            sc // chunk, chunk, length, unroll=cfg.unroll_layers,
-            new_kv=(*_read_kv(cfg, new), slot))
+            sc // chunk, chunk, length, new_kv=(*_read_kv(cfg, new), slot))
     else:
         o = attn_mod.decode_attention(q, *read(0, sc), length,
                                       new_kv=(*_read_kv(cfg, new), slot))
     cache = {name: jax.lax.dynamic_update_slice(
                  a, new[name][None], (at, 0, slot, 0))
              for name, a in cache.items()}
-    o = o.reshape(b, 1, hq * hd) @ p["wo"].astype(compute_dtype)
+    o = o.reshape(b, 1, hq * hd) @ layer["attn"]["wo"].astype(compute_dtype)
     return x + o.astype(x.dtype), cache
 
 
@@ -626,15 +590,9 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict,
 
     layers = tuple(cache["layers"])
     if cfg.n_groups > 0:
-        if cfg.unroll_layers:
-            for g in range(cfg.n_groups):
-                (x, layers), _ = group_body(
-                    (x, layers), (g, _group_slice(tuple(params["layers"]),
-                                                  g)))
-        else:
-            (x, layers), _ = jax.lax.scan(
-                group_body, (x, layers),
-                (jnp.arange(cfg.n_groups), tuple(params["layers"])))
+        (x, layers), _ = jax.lax.scan(
+            group_body, (x, layers),
+            (jnp.arange(cfg.n_groups), tuple(params["layers"])))
     new_tail = []
     for i, mixer in enumerate(cfg.tail_pattern):
         one = jax.tree.map(lambda a: a[None], cache["tail"][i])
@@ -651,27 +609,9 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict,
 # ---------------------------------------------------------------------------
 def _attn_prefill(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
                   cos, sin, cache_layer: dict):
-    compute_dtype = _cd(cfg)
     """Full attention layer computing q/k/v once: returns (x', cache')."""
-    b, s, d = x.shape
-    p = layer["attn"]
-    h = rms_norm(x, layer["norm1"]).astype(compute_dtype)
-    hq, hkv, hd = cfg.q_heads, cfg.kv_heads, cfg.head_dim
-    q = h @ p["wq"].astype(compute_dtype)
-    k = h @ p["wk"].astype(compute_dtype)
-    v = h @ p["wv"].astype(compute_dtype)
-    if cfg.qkv_bias:
-        q = q + p["bq"].astype(compute_dtype)
-        k = k + p["bk"].astype(compute_dtype)
-        v = v + p["bv"].astype(compute_dtype)
-    q = q.reshape(b, s, hq, hd)
-    k = k.reshape(b, s, hkv, hd)
-    v = v.reshape(b, s, hkv, hd)
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    b, s, _ = x.shape
+    q, k, v = _qkv(layer, cfg, x, cos, sin)
     sc = cache_layer["k"].shape[1]
     if sc < s:   # ring buffer: only the last sc positions survive
         idx = jnp.arange(s - sc, s) % sc
@@ -682,9 +622,10 @@ def _attn_prefill(layer: dict, cfg: ModelConfig, mixer: str, x: jax.Array,
               **_store_kv(cfg, cache_layer, k, v, jnp.arange(s))}
     window = cfg.window if mixer == "swa" else None
     o = attn_mod.attention(q, k, v, impl=cfg.attn_impl, window=window,
-                           chunk=cfg.attn_chunk, unroll=cfg.unroll_layers,
-                           score_dtype=_sd(cfg), gqa_grouped=cfg.gqa_grouped)
-    o = o.reshape(b, s, hq * hd) @ p["wo"].astype(compute_dtype)
+                           chunk=cfg.attn_chunk, score_dtype=_sd(cfg),
+                           gqa_grouped=cfg.gqa_grouped)
+    o = o.reshape(b, s, cfg.q_heads * cfg.head_dim) \
+        @ layer["attn"]["wo"].astype(_cd(cfg))
     return x + o.astype(x.dtype), nc
 
 
@@ -740,19 +681,10 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
         body = jax.checkpoint(group_body, prevent_cse=False)
     new_cache: dict[str, Any] = {"pos": jnp.asarray(s, jnp.int32)}
     if cfg.n_groups > 0:
-        if cfg.unroll_layers:
-            collected = []
-            for g in range(cfg.n_groups):
-                x, ncg = body(
-                    x, (_group_slice(tuple(params["layers"]), g),
-                        _group_slice(tuple(cache["layers"]), g)))
-                collected.append(ncg)
-            new_cache["layers"] = list(_stack_groups(collected))
-        else:
-            x, ncl = jax.lax.scan(body, x,
-                                  (tuple(params["layers"]),
-                                   tuple(cache["layers"])))
-            new_cache["layers"] = list(ncl)
+        x, ncl = jax.lax.scan(body, x,
+                              (tuple(params["layers"]),
+                               tuple(cache["layers"])))
+        new_cache["layers"] = list(ncl)
     else:
         new_cache["layers"] = cache["layers"]
     new_tail = []

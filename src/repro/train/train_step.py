@@ -1,8 +1,9 @@
 """Jitted training step: loss -> grads -> AdamW, with sharding specs.
 
 ``make_train_step`` returns a jitted function with in/out shardings bound
-to the mesh (donated params/opt-state buffers) — this is exactly the
-callable the multi-pod dry-run lowers with ShapeDtypeStructs.
+to the mesh (donated params/opt-state buffers) — the callable the trainer
+runs, and the one tests compile from ShapeDtypeStructs on fake or
+described meshes.
 """
 from __future__ import annotations
 

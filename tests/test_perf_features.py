@@ -1,7 +1,7 @@
 """Tests for the §Perf / feasibility features added beyond the baseline:
 microbatched gradient accumulation, fp32-master mixed precision, blocked
-decode attention, attention score-dtype / grouped-GQA levers, chunked
-rwkv6 backward memory, and the dry-run regeneration ladder policy."""
+decode attention, attention score-dtype / grouped-GQA levers, and chunked
+rwkv6 backward memory."""
 from __future__ import annotations
 
 import dataclasses
@@ -124,21 +124,6 @@ def test_blocked_decode_matches_unblocked(kv_dtype):
                                rtol=tol, atol=tol)
 
 
-def test_blocked_decode_unroll_equivalent():
-    cfg0 = dataclasses.replace(smoke(get_config("qwen3-0.6b")),
-                               compute_dtype="float32",
-                               kv_cache_dtype="float32", decode_chunk=8)
-    cfg1 = dataclasses.replace(cfg0, unroll_layers=True)
-    params = M.init_params(cfg0, jax.random.PRNGKey(0))
-    toks = jax.random.randint(jax.random.PRNGKey(3), (2, 13), 0,
-                              cfg0.vocab)
-    _, cache = M.prefill(params, cfg0, toks[:, :12], max_len=32)
-    l0, _ = M.decode_step(params, cfg0, cache, toks[:, 12])
-    l1, _ = M.decode_step(params, cfg1, cache, toks[:, 12])
-    np.testing.assert_allclose(np.asarray(l0), np.asarray(l1),
-                               rtol=1e-5, atol=1e-5)
-
-
 # ---------------------------------------------------------------------------
 # attention levers
 # ---------------------------------------------------------------------------
@@ -183,21 +168,3 @@ def test_rwkv6_chunked_grad_correct():
     np.testing.assert_allclose(np.asarray(g16), np.asarray(g64),
                                rtol=1e-4, atol=1e-4)
     assert loss(16) == pytest.approx(loss(64), rel=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# regeneration ladder policy
-# ---------------------------------------------------------------------------
-def test_regeneration_ladder_shapes():
-    import importlib
-    jax.devices()        # pin the backend BEFORE dryrun sets XLA_FLAGS
-    dr = importlib.import_module("repro.launch.dryrun")
-    for kind in ("train", "prefill", "decode"):
-        ladder = dr.regeneration_ladder(kind)
-        assert len(ladder) >= 1
-        for label, patch, mb in ladder:
-            assert isinstance(label, str) and isinstance(patch, dict)
-            assert mb >= 1
-    # train rungs escalate microbatches monotonically
-    mbs = [mb for _, _, mb in dr.regeneration_ladder("train")]
-    assert mbs == sorted(mbs)
